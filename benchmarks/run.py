@@ -44,6 +44,7 @@ from repro.core.arrivals import EnvelopeSpec, generate_fleet_trace
 from repro.core.fleet import FleetConfig, run_fleet
 from repro.core.mc_sweep import MCAxes, sharded_mc_sweep
 from repro.core.sweep import SweepAxes, sharded_sweep, sweep
+from repro.runtime.compile_cache import enable_compile_cache
 
 REGISTRY = {}
 _FLEET_CACHE: Dict[tuple, fleet.FleetResult] = {}
@@ -410,12 +411,14 @@ def sweep_speedup():
     jit cache, while sequential lifecycles recompile per trace shape —
     exactly the workflow the sweep engine batches.
 
-    Acceptance (ISSUE 2): additionally emits the sharded-vs-single-device
-    ratio (`sweep.sharded_speedup`) on ≥2 devices.  When this process
-    sees only one device, the sharded leg re-runs in a subprocess with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=2`` (host devices
-    are time-sliced cores there, so the ratio measures overhead, not
-    speedup — real scaling needs real devices)."""
+    It also emits the sharded-vs-single-device ratio
+    (`sweep.sharded_speedup`) on ≥2 devices, in this process.  A CPU
+    process that sees one device re-runs the sharded leg in a CPU child
+    with ``XLA_FLAGS=--xla_force_host_platform_device_count=2`` (host
+    devices are time-sliced cores there, so the ratio measures overhead,
+    not speedup — real scaling needs real devices).  One accelerator
+    device gives a ``skipped=single_device`` row: the chip belongs to
+    this process, and no child may need it."""
     scale = min(SCALE, 0.01)
 
     _, warm_axes = _speedup_grid(scale, (101, 102))
@@ -447,8 +450,11 @@ def sweep_speedup():
     import jax
     if jax.device_count() >= 2:
         _sharded_probe(scale)
-    else:
-        env = dict(os.environ)
+    elif jax.default_backend() == "cpu":
+        # a CPU parent holds no chip, so a child pinned to the CPU may
+        # force two host devices; a child that needed this process's
+        # accelerator would fail or hang behind it
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             " --xla_force_host_platform_device_count=2"
                             ).strip()
@@ -458,6 +464,9 @@ def sweep_speedup():
         if r.returncode != 0:
             emit("sweep.sharded_speedup", 0,
                  f"error=probe_subprocess_rc{r.returncode}")
+    else:
+        emit("sweep.sharded_speedup", 0,
+             f"skipped=single_device;backend={jax.default_backend()}")
 
 
 _LEGACY_MC_JIT = None
@@ -669,7 +678,7 @@ def mc_pod_speedup():
 
 @bench
 def placement_kernel_speedup():
-    """Acceptance (ISSUE 7): the fused Pallas placement-score kernel
+    """Acceptance: the Pallas placement-feasibility kernel
     behind `use_kernel=True`.
 
     Always runs the equivalence leg — a pod-heavy single-hall MC grid
@@ -802,30 +811,33 @@ def giant_grid():
         designs=[hierarchy.get_design(d) for d in dnames],
         envs=[probe_env], seeds=[41, 42])
 
-    def temp_bytes(exact_q):
+    def memory(exact_q):
+        """The compiled program's memory analysis; None where the
+        backend has none.  A compile error raises."""
         from repro.core.sweep import _prepare, _sweep_jit
         args, *_, with_pods, pod_len, hd_scan = _prepare(
             probe, probe_hmax, None)
-        compiled = _sweep_jit.lower(
+        return _sweep_jit.lower(
             *args, harvest=True, mature_months=12, with_pods=with_pods,
             legacy_pod_cond=False, pod_scan_len=pod_len, hd_scan=hd_scan,
             use_kernel=placement.resolve_use_kernel(None),
             kernel_interpret=False, exact_quantiles=exact_q,
-            quantile_bins=None).compile()
-        return int(compiled.memory_analysis().temp_size_in_bytes)
+            quantile_bins=None).compile().memory_analysis()
 
-    try:
-        b_ex, b_st = temp_bytes(True), temp_bytes(False)
-        emit("giant_grid.mem_speedup", 0,
-             f"exact_over_stream_temp={b_ex / max(b_st, 1):.2f}x;"
-             f"exact_temp_mb={b_ex / 1e6:.2f};"
-             f"stream_temp_mb={b_st / 1e6:.2f};"
-             f"history_mb={(b_ex - b_st) / 1e6:.2f};"
-             f"n_cfg={len(probe)};n_halls_max={probe_hmax}")
-    except Exception as e:   # backend without memory_analysis
+    m_ex, m_st = memory(True), memory(False)
+    if m_ex is None or m_st is None:
+        import jax
         emit("giant_grid.mem_speedup", 0,
              f"skipped=memory_analysis_unavailable;"
-             f"err={type(e).__name__}")
+             f"backend={jax.default_backend()}")
+        return
+    b_ex, b_st = int(m_ex.temp_size_in_bytes), int(m_st.temp_size_in_bytes)
+    emit("giant_grid.mem_speedup", 0,
+         f"exact_over_stream_temp={b_ex / max(b_st, 1):.2f}x;"
+         f"exact_temp_mb={b_ex / 1e6:.2f};"
+         f"stream_temp_mb={b_st / 1e6:.2f};"
+         f"history_mb={(b_ex - b_st) / 1e6:.2f};"
+         f"n_cfg={len(probe)};n_halls_max={probe_hmax}")
 
 
 def _resilience_grid(n_cfg):
@@ -1044,6 +1056,7 @@ def main(argv=None):
                     help="internal: run only the multi-device leg of "
                          "sweep_speedup (expects forced host devices)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     SCALE = args.scale
     SMOKE = args.smoke
     if args.sharded_probe:

@@ -22,6 +22,7 @@ import jax
 from repro.core import hierarchy, projections as proj
 from repro.core.arrivals import EnvelopeSpec
 from repro.core.sweep import SweepAxes, sharded_sweep
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--scenarios", nargs="+",
                     default=[proj.LOW, proj.MED, proj.HIGH])
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = ("4N/3", "3+1", "10N/8", "8+2")
     combos = [(s, n) for s in args.scenarios for n in names]

@@ -21,6 +21,7 @@ import jax
 
 from repro.core import payoff, throughput as tp
 from repro.core.arrivals import EnvelopeSpec
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def plot(pts, model, path):
@@ -66,6 +67,7 @@ def main():
     ap.add_argument("--plot", default=None, metavar="PNG",
                     help="write the frontier figure (needs matplotlib)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     env = EnvelopeSpec(demand_scale=args.scale, gpu_scenario="high")
     t0 = time.time()

@@ -5,9 +5,11 @@ serving gains survive their deployability cost?
 """
 from repro.core import hierarchy, payoff, throughput as tp
 from repro.core.arrivals import EnvelopeSpec
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     env = EnvelopeSpec(demand_scale=0.03, gpu_scenario="high",
                        pod_scale_arch=True)
     models = [tp.MODELS[n] for n in

@@ -14,9 +14,11 @@ from repro.core import throughput as tp
 from repro.core.arrivals import EnvelopeSpec
 from repro.core.fleet import FleetConfig, run_fleet
 from repro.core.mc_sweep import MCAxes, mc_sweep
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     d43, d31 = hierarchy.design_4n3(), hierarchy.design_3p1()
 
     print("== static commissioning metrics (paper §3.1) ==")

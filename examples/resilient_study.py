@@ -24,6 +24,7 @@ from repro.core.resilience import (FaultPlan, InjectedCrash,
                                    resilient_sweep)
 from repro.core.sweep import SweepAxes, sweep
 from repro.runtime.fault import Backoff
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--scale", type=float, default=0.01)
     ap.add_argument("--chunk", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = ("4N/3", "3+1")
     combos = [(n, s, sd) for n in names for s in (proj.MED, proj.HIGH)

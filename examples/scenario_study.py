@@ -18,6 +18,7 @@ import jax
 
 from repro.core import hierarchy, payoff, scenarios as sc
 from repro.core.arrivals import EnvelopeSpec
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
                     help="restrict to one scenario family")
     ap.add_argument("--seeds", nargs="+", type=int, default=[0])
     args = ap.parse_args()
+    enable_compile_cache()
 
     base = EnvelopeSpec(demand_scale=args.scale)
     families = sc.all_families(base)

@@ -306,7 +306,7 @@ def simulate_lifecycle(jt: JaxTopology, ft: FleetTrace, idx, valid,
       measures the split-trace win against exactly this path.
 
     `use_kernel` / `kernel_interpret` (static) route every placement's
-    feasibility + variance score through the fused Pallas kernel
+    line-up power feasibility through the Pallas kernel
     (bitwise-identical results; see `placement.place_in_row`).
 
     `exact_quantiles` (static) selects the p50/p90 stranding path:
@@ -569,8 +569,9 @@ def run_fleet(cfg: FleetConfig, trace: Trace | None = None,
         cfg: design/envelope/policy/seed bundle (see `FleetConfig`).
         trace: optional pre-generated arrival trace; defaults to
             `generate_fleet_trace(cfg.env, cfg.seed)`.
-        use_kernel: route placement scoring through the fused Pallas
-            kernel (bitwise-identical results); `None` = backend default
+        use_kernel: route placement's line-up power feasibility through
+            the Pallas kernel (bitwise-identical results); `None` = backend
+            default
             (`placement.default_use_kernel`: TPU on, CPU off).
         kernel_interpret: run the kernel in Pallas interpret mode (CPU
             CI fallback; only meaningful with the kernel path on).

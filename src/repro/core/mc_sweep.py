@@ -258,8 +258,8 @@ def _mc_sharded_jit(jt, ta, tb, keys, policy, mesh, harvest, with_pods,
         cluster_starts=cluster_starts, pod_scan_len=pod_scan_len,
         hd_scan=hd_scan, use_kernel=use_kernel,
         kernel_interpret=kernel_interpret))
-    sharded = shax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 5,
-                             out_specs=spec, check_vma=False)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 5,
+                            out_specs=spec, check_vma=False)
     return sharded(jt, ta, tb, keys, policy)
 
 
@@ -306,9 +306,9 @@ def _mc_sharded2d_jit(jt, ta, tb, keys, policy, mesh, harvest, with_pods,
         return jax.tree.map(
             lambda x: x.reshape((b, t) + x.shape[1:]), out)
 
-    sharded = shax.shard_map(shard_fn, mesh=mesh,
-                             in_specs=(cspec, gspec, gspec, gspec, cspec),
-                             out_specs=gspec, check_vma=False)
+    sharded = jax.shard_map(shard_fn, mesh=mesh,
+                            in_specs=(cspec, gspec, gspec, gspec, cspec),
+                            out_specs=gspec, check_vma=False)
     return sharded(jt, ta, tb, keys, policy)
 
 
@@ -474,8 +474,8 @@ def mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
         models: Table 2 models (objects or names) for the per-trial
             $/performance columns (default `throughput.MODEL_SUITE`;
             `()` skips the stage).
-        use_kernel: route placement scoring through the fused Pallas
-            kernel (static; bitwise-identical results).  `None` = backend
+        use_kernel: route placement's line-up power feasibility through
+            the Pallas kernel (static; bitwise-identical results).  `None` = backend
             default: on for TPU, off elsewhere
             (`placement.default_use_kernel`).
         kernel_interpret: run the kernel in Pallas interpret mode (the
@@ -576,7 +576,8 @@ def sharded_mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
                                 harvest=harvest, mesh=mesh,
                                 use_kernel=pl.resolve_use_kernel(use_kernel),
                                 kernel_interpret=kernel_interpret, **statics)
-        out = jax.tree.map(lambda x: x[:B, :T], out)
+        # drop padding on the host (see `sweep.sharded_sweep`)
+        out = jax.tree.map(lambda x: np.asarray(x)[:B, :T], out)
     else:
         # ---- flat path: repeat per-config leaves per trial and shard
         # the [B·T] axis over the whole mesh ----
@@ -599,8 +600,8 @@ def sharded_mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
         out = _mc_sharded_jit(*args, harvest=harvest, mesh=mesh,
                               use_kernel=pl.resolve_use_kernel(use_kernel),
                               kernel_interpret=kernel_interpret, **statics)
-        out = jax.tree.map(
-            lambda x: x[:B * T].reshape((B, T) + x.shape[1:]), out)
+        out = jax.tree.map(lambda x: np.asarray(x)[:B * T].reshape(
+            (B, T) + x.shape[1:]), out)
     return _mc_finalize(out, axes, models=models, year=year,
                         scenario=scenario,
                         gpu_share=1.0 if single_sku_gpu else gpu_power_share,

@@ -33,7 +33,8 @@ import numpy as np
 
 from .hierarchy import HallTopology, MAX_FEEDS
 from .resources import LIQ, N_RES, POWER, TIER_HA, rack_demand
-from ..kernels.placement_score.ops import score_rows as _kernel_score_rows
+from ..kernels.placement_score.ops import (
+    feasible_rows as _kernel_feasible_rows)
 
 # Policy ids (paper §4.2).
 POLICY_RANDOM, POLICY_ROUND_ROBIN, POLICY_MIN_WASTE, POLICY_VAR_MIN = 0, 1, 2, 3
@@ -45,10 +46,11 @@ _BIG = 1e30
 _LD_PREFERENCE = 100.0  # non-GPU racks prefer LD rows (paper §2.2)
 
 # Pallas kernel path (see docs/architecture.md "kernel path").  The row
-# block size trades VMEM footprint against grid steps; 128 rows × 8-lane
-# feed tiles stay far under VMEM for every in-repo topology, and
+# block size trades VMEM footprint against grid steps; rows lie on the
+# 128-wide lane axis, so it must be a multiple of 128, and
 # `kernels.placement_score.kernel.placement_score` pads the row axis to
-# a multiple internally, so the value is a tile size, not a constraint.
+# a multiple internally, so the value is a tile size, not a constraint
+# on the row count.
 DEFAULT_BLOCK_R = 128
 
 
@@ -192,22 +194,17 @@ def _row_fits(jt: JaxTopology, state: HallState, dep: Deployment,
     return fits_row & hd_ok & liq_ok
 
 
-def _kernel_feas_scores(jt: JaxTopology, state: HallState, dep: Deployment,
-                        n_in_row, rows=None, interpret: bool = False,
-                        block_r: int = DEFAULT_BLOCK_R):
-    """Fused power-feasibility + variance scores via the Pallas kernel.
-
-    Returns (kernel_feas [R|K] bool, var [R|K] f32).  `kernel_feas` is
-    the power condition AND the row *power* fit — a superset of the full
+def _kernel_feasible(jt: JaxTopology, state: HallState, dep: Deployment,
+                     n_in_row, rows=None, interpret: bool = False,
+                     block_r: int = DEFAULT_BLOCK_R) -> jax.Array:
+    """Line-up power feasibility via the Pallas kernel: [R|K] bool, the
+    power condition AND the row *power* fit.  A superset of the full
     feasibility (`row_feasible` additionally checks the other resources,
-    HD and liquid), so callers AND it with `_row_fits`.  `var` equals
-    the jnp variance score bitwise at every kernel-feasible row and is
-    `kernels.placement_score.kernel.BIG` elsewhere — rows the final
-    feasibility mask sends to `_BIG` anyway."""
+    HD and liquid), so callers AND it with `_row_fits`."""
     n = jnp.asarray(n_in_row, jnp.float32)
     P = n * dep.rack_kw
     r_cap, r_load, r_feeds, r_nfeeds, _, _ = _row_view(jt, state, rows)
-    return _kernel_score_rows(
+    return _kernel_feasible_rows(
         r_feeds, r_nfeeds, r_cap[:, POWER], state.lineup_ha,
         state.lineup_tot, jt.lineup_cap, r_load[:, POWER], P, jt.ha_frac,
         dep.tier == TIER_HA, jt.is_block, block_r=block_r,
@@ -223,14 +220,13 @@ def row_feasible(jt: JaxTopology, state: HallState, dep: Deployment,
     HD-compacted pod scan's view.
 
     `use_kernel=True` (static) computes the line-up power condition with
-    the fused Pallas kernel instead of the jnp gather; the result is
+    the Pallas kernel instead of the jnp comparisons; the result is
     bitwise identical (`tests/test_placement_kernel.py`).  `interpret`
     runs the kernel in Pallas interpret mode (CPU CI)."""
     extra = _row_fits(jt, state, dep, n_in_row, rows)
     if use_kernel:
-        kfeas, _ = _kernel_feas_scores(jt, state, dep, n_in_row, rows,
-                                       interpret=interpret)
-        return extra & kfeas
+        return extra & _kernel_feasible(jt, state, dep, n_in_row, rows,
+                                        interpret=interpret)
 
     n = jnp.asarray(n_in_row, jnp.float32)
     P = n * dep.rack_kw
@@ -256,22 +252,11 @@ def row_feasible(jt: JaxTopology, state: HallState, dep: Deployment,
 
 
 def row_scores(jt: JaxTopology, state: HallState, dep: Deployment,
-               n_in_row, policy, key, rows=None, var=None,
-               use_kernel: bool = False, interpret: bool = False
-               ) -> jax.Array:
+               n_in_row, policy, key, rows=None) -> jax.Array:
     """Per-row placement score (lower is better).  With `rows`, scores are
     the full-row scores gathered at the subset (the random draw is taken
     from the full-`R` grid and the round-robin distance keeps full-`R`
-    row ids), so a compacted argmin matches the full argmin bitwise.
-
-    `var` (optional, [R|K]) short-circuits the variance-score column —
-    `place_in_row`'s kernel path passes the kernel's fused output so the
-    feed gather runs once.  `use_kernel=True` computes it here via the
-    kernel instead.  Either way the variance column carries the kernel's
-    `BIG` mask at kernel-infeasible rows; callers mask scores by
-    feasibility before the argmin (as `place_in_row` does), so selection
-    is unaffected — standalone callers comparing raw scores against the
-    jnp path should compare at feasible rows."""
+    row ids), so a compacted argmin matches the full argmin bitwise."""
     n = jnp.asarray(n_in_row, jnp.float32)
     P = n * dep.rack_kw
     R = jt.row_cap.shape[0]
@@ -287,16 +272,11 @@ def row_scores(jt: JaxTopology, state: HallState, dep: Deployment,
     waste = (r_cap[:, POWER] - r_load[:, POWER] - P) / \
         jnp.maximum(r_cap[:, POWER], 1.0)
 
-    if var is None and use_kernel:
-        _, var = _kernel_feas_scores(jt, state, dep, n_in_row, rows,
-                                     interpret=interpret)
-    if var is None:
-        valid, _, cap, ha_l, tot_l = _gather_feeds(jt, state, r_feeds)
-        nf = jnp.maximum(r_nfeeds, 1).astype(jnp.float32)
-        s = (P / nf)[:, None] / jnp.maximum(cap, 1.0)
-        lhat = jnp.where(dep.tier == TIER_HA, ha_l, tot_l) / \
-            jnp.maximum(cap, 1.0)
-        var = jnp.sum(jnp.where(valid, 2.0 * lhat * s + s * s, 0.0), axis=-1)
+    valid, _, cap, ha_l, tot_l = _gather_feeds(jt, state, r_feeds)
+    nf = jnp.maximum(r_nfeeds, 1).astype(jnp.float32)
+    s = (P / nf)[:, None] / jnp.maximum(cap, 1.0)
+    lhat = jnp.where(dep.tier == TIER_HA, ha_l, tot_l) / jnp.maximum(cap, 1.0)
+    var = jnp.sum(jnp.where(valid, 2.0 * lhat * s + s * s, 0.0), axis=-1)
 
     score = jnp.select(
         [policy == POLICY_RANDOM, policy == POLICY_ROUND_ROBIN,
@@ -340,24 +320,16 @@ def place_in_row(jt: JaxTopology, state: HallState, dep: Deployment,
     scan: GPU racks are HD-only), the result is bitwise identical to the
     full scan.
 
-    `use_kernel=True` (static) runs ONE fused Pallas kernel call for the
-    line-up power feasibility and the variance score instead of two jnp
-    feed gathers; `interpret` runs it in Pallas interpret mode.  Chosen
-    rows, state updates and `ok` are bitwise identical to the jnp path:
-    kernel feasibility is AND-ed with the identical row/hall constraints,
-    and the kernel's `BIG`-masked variance column only differs at rows
-    the feasibility mask sends to `_BIG` anyway."""
-    if use_kernel:
-        kfeas, kvar = _kernel_feas_scores(jt, state, dep, n_in_row,
-                                          rows=row_subset,
-                                          interpret=interpret)
-        feas = _row_fits(jt, state, dep, n_in_row, rows=row_subset) & kfeas
-        score = row_scores(jt, state, dep, n_in_row, policy, key,
-                           rows=row_subset, var=kvar)
-    else:
-        feas = row_feasible(jt, state, dep, n_in_row, rows=row_subset)
-        score = row_scores(jt, state, dep, n_in_row, policy, key,
-                           rows=row_subset)
+    `use_kernel=True` (static) computes the line-up power feasibility
+    with the Pallas kernel (`row_feasible`); `interpret` runs it in
+    Pallas interpret mode.  Scores are the jnp `row_scores` either way,
+    so chosen rows, state updates and `ok` are bitwise identical to the
+    jnp path: the kernel's feasibility is AND-ed with the identical
+    row/hall constraints, and its comparisons see the same shares."""
+    feas = row_feasible(jt, state, dep, n_in_row, rows=row_subset,
+                        use_kernel=use_kernel, interpret=interpret)
+    score = row_scores(jt, state, dep, n_in_row, policy, key,
+                       rows=row_subset)
     if row_subset is None:
         feas = feas & row_active
         if score_bias is not None:
@@ -555,4 +527,14 @@ def hall_stranding(jt: JaxTopology, state: HallState) -> jax.Array:
 
 
 def deployed_kw(state: HallState) -> jax.Array:
-    return jnp.sum(state.row_load[:, POWER])
+    """Deployed power: the rows' power loads added in a fixed pairwise
+    order.  `jnp.sum` leaves the order to the compiler, and on a TPU v5e
+    programs that differ only in batch shape or placement path ordered
+    it differently, moving the total by a few ulps; explicit adds keep
+    it bitwise equal across them (padding adds exact zeros)."""
+    x = state.row_load[:, POWER]
+    n = 1 << (x.shape[0] - 1).bit_length()
+    x = jnp.pad(x, (0, n - x.shape[0]))
+    while x.shape[0] > 1:
+        x = x[:x.shape[0] // 2] + x[x.shape[0] // 2:]
+    return x[0]

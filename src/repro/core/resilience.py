@@ -29,6 +29,9 @@ wraps the chunked dispatch from the sweep engines with three guarantees:
   the same way.  OOM (real `RESOURCE_EXHAUSTED` or injected) halves the
   dispatch size — stickily, so later chunks stream at the size that
   fits — while the checkpoint grid keeps the original chunk boundaries.
+  A program that fails to lower or compile is not a poisoned
+  configuration: every configuration would hit it, so the error is
+  raised (`CompileError`) before anything is dispatched or quarantined.
 
 * **Validated inputs** — `axes.validate()` runs before any compile time
   is spent (`SweepValidationError` with the offending field).
@@ -97,6 +100,14 @@ class InjectedFault(RuntimeError):
 class InjectedCrash(RuntimeError):
     """Injected process death after a chunk commit (kill-and-resume
     tests); escapes `resilient_sweep` by design."""
+
+
+class CompileError(RuntimeError):
+    """The engine program failed to lower or compile (for example a
+    kernel the backend's compiler refuses).  That is a fault of the
+    program or the backend, not of any configuration, so the executor
+    raises it instead of retrying, bisecting or quarantining; the
+    compiler's own exception is chained as ``__cause__``."""
 
 
 class ResumeMismatchError(RuntimeError):
@@ -270,16 +281,17 @@ def _is_oom(e: BaseException) -> bool:
 
 class _ChunkExecutor:
     """Evaluate `B` configurations in chunks with checkpointing, retry,
-    bisection quarantine, and OOM halving.  `raw_eval(lo, hi)` returns
-    the device output pytree for configurations `[lo, hi)` of the
-    globally prepared batch; `fields` orders its leaves into the slab
-    dict; NaN in a `detect` field marks a poisoned row."""
+    bisection quarantine, and OOM halving.  `ranges` is a `_RangeEval`
+    over the globally prepared batch: `compile(lo, hi)` returns the
+    dispatch for configurations `[lo, hi)`, and `shapes(lo, hi)` its
+    output shapes; `fields` orders the output leaves into the slab dict;
+    NaN in a `detect` field marks a poisoned row."""
 
-    def __init__(self, raw_eval: Callable, fields: Sequence[str],
+    def __init__(self, ranges: "_RangeEval", fields: Sequence[str],
                  detect: Sequence[str], B: int, chunk_size: int,
                  checkpoint_dir: Optional[str], plan: Optional[FaultPlan],
                  backoff: Optional[Backoff]):
-        self.raw_eval = raw_eval
+        self.ranges = ranges
         self.fields = tuple(fields)
         self.detect = tuple(detect)
         self.B = B
@@ -300,7 +312,7 @@ class _ChunkExecutor:
     def _nan_slab(self, lo: int, hi: int) -> Dict[str, np.ndarray]:
         """Sentinel slab for quarantined rows: floats NaN, ints −1,
         bools False.  Shapes come from `jax.eval_shape` (no compile)."""
-        shapes = jax.eval_shape(lambda: self.raw_eval(lo, hi))
+        shapes = self.ranges.shapes(lo, hi)
         leaves = (shapes if isinstance(shapes, tuple)
                   and not hasattr(shapes, "_fields")
                   else [getattr(shapes, f) for f in self.fields])
@@ -345,7 +357,15 @@ class _ChunkExecutor:
         while True:
             try:
                 self.plan.before_eval(chunk, lo, hi, chunk_lo, chunk_hi)
-                slab = self._to_slab(self.raw_eval(lo, hi))
+                try:
+                    dispatch = self.ranges.compile(lo, hi)
+                except Exception as e:  # noqa: BLE001 — classified here
+                    if _is_oom(e):      # program too large: halve below
+                        raise
+                    raise CompileError(
+                        f"the engine program failed to compile for "
+                        f"configurations [{lo}, {hi}): {e}") from e
+                slab = self._to_slab(dispatch())
                 slab = self.plan.after_eval(lo, hi, slab)
                 bad = self._bad_rows(slab)
                 if not bad.any():
@@ -362,7 +382,7 @@ class _ChunkExecutor:
                                      chunk_hi, 0),
                     self._eval_range(report, chunk, mid, hi, chunk_lo,
                                      chunk_hi, 0)])
-            except InjectedCrash:
+            except (InjectedCrash, CompileError):
                 raise
             except Exception as e:      # noqa: BLE001 — isolate anything
                 if _is_oom(e):
@@ -466,21 +486,49 @@ class _ChunkExecutor:
 # front doors
 # ---------------------------------------------------------------------------
 
-def _sliced_eval(args, jit_fn, statics: dict):
-    """Range evaluator over the globally prepared batch.  A width-1 vmap
-    compiles a degenerate batch whose accumulation order differs bitwise
-    from wider dispatches (observed on XLA:CPU), so single-config ranges
-    duplicate their row to width 2 and keep row 0 — bitwise identical to
-    the same row inside any wider dispatch."""
-    def raw_eval(lo, hi):
+class _RangeEval:
+    """Range evaluator over the globally prepared batch.  Compiling
+    and dispatching are separate steps, so the executor can tell a
+    program the compiler refuses from a configuration that crashes.
+    Executables are kept per dispatch width: every range of one width
+    has the same shapes, and so shares one executable.
+
+    A width-1 vmap compiles a degenerate batch whose accumulation order
+    differs bitwise from wider dispatches (observed on XLA:CPU), so
+    single-config ranges duplicate their row to width 2 and keep row 0 —
+    bitwise identical to the same row inside any wider dispatch."""
+
+    def __init__(self, args, jit_fn, statics: dict):
+        self.args, self.jit_fn, self.statics = args, jit_fn, statics
+        self._compiled: Dict[int, Callable] = {}
+
+    def _slice(self, lo: int, hi: int):
         if hi - lo == 1:
             idx = jnp.asarray([lo, lo])
-            sl = jax.tree.map(lambda x: x[idx], args)
-            out = jit_fn(*sl, **statics)
-            return jax.tree.map(lambda x: x[:1], out)
-        sl = jax.tree.map(lambda x: x[lo:hi], args)
-        return jit_fn(*sl, **statics)
-    return raw_eval
+            return jax.tree.map(lambda x: x[idx], self.args)
+        return jax.tree.map(lambda x: x[lo:hi], self.args)
+
+    def compile(self, lo: int, hi: int) -> Callable:
+        """Lower and compile for `[lo, hi)` (raises what the compiler
+        raises); returns the zero-argument dispatch of that range."""
+        sl = self._slice(lo, hi)
+        width = max(hi - lo, 2)
+        if width not in self._compiled:
+            self._compiled[width] = self.jit_fn.lower(
+                *sl, **self.statics).compile()
+        exe = self._compiled[width]
+        if hi - lo == 1:
+            return lambda: jax.tree.map(lambda x: x[:1], exe(*sl))
+        return lambda: exe(*sl)
+
+    def shapes(self, lo: int, hi: int):
+        """Output shapes of `[lo, hi)` from `jax.eval_shape` (no
+        compile)."""
+        out = jax.eval_shape(lambda: self.jit_fn(*self._slice(lo, hi),
+                                                 **self.statics))
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((hi - lo,) + s.shape[1:],
+                                           s.dtype), out)
 
 
 def _mask_rows(report: RunReport, *arrays: np.ndarray) -> None:
@@ -541,8 +589,8 @@ def resilient_sweep(axes: SweepAxes, chunk_size: int | None = None,
     B = len(axes)
     chunk = chunk_size if chunk_size is not None else B
 
-    raw_eval = _sliced_eval(args, _sweep_jit, statics)
-    ex = _ChunkExecutor(raw_eval, SWEEP_FIELDS,
+    ex = _ChunkExecutor(_RangeEval(args, _sweep_jit, statics),
+                        SWEEP_FIELDS,
                         detect=("final_deployed_kw", "placed_fraction"),
                         B=B, chunk_size=chunk,
                         checkpoint_dir=checkpoint_dir, plan=fault_plan,
@@ -584,8 +632,8 @@ def resilient_mc_sweep(axes: MCAxes, chunk_size: int | None = None,
     B = len(axes)
     chunk = chunk_size if chunk_size is not None else B
 
-    raw_eval = _sliced_eval(args, _mc_sweep_jit, kw)
-    ex = _ChunkExecutor(raw_eval, MC_FIELDS, detect=("deployed_kw",),
+    ex = _ChunkExecutor(_RangeEval(args, _mc_sweep_jit, kw), MC_FIELDS,
+                        detect=("deployed_kw",),
                         B=B, chunk_size=chunk,
                         checkpoint_dir=checkpoint_dir, plan=fault_plan,
                         backoff=backoff)

@@ -86,8 +86,8 @@ def _fill_phase(jt: JaxTopology, state: HallState, trace: TraceArrays,
       and the per-event `fold_in(key, i)` keys are exactly the legacy
       path's, so results are bit-identical.
 
-    `use_kernel` (static) routes every placement's feasibility + score
-    through the fused Pallas kernel (`placement.place_in_row`), with
+    `use_kernel` (static) routes every placement's line-up power
+    feasibility through the Pallas kernel (`placement.place_in_row`), with
     `kernel_interpret` selecting Pallas interpret mode (CPU CI); results
     are bitwise identical to the jnp path in every mode.
     """
@@ -205,9 +205,9 @@ def run_trial(jt: JaxTopology, topo_init: HallState,
     `split_pods=True` compiles the split-trace pod fast path —
     `pod_windows` / `cluster_starts` are the (fill, refill) window bounds
     and `pod_scan_len` / `hd_scan` the pod rack-scan trims (see
-    `_fill_phase`).  `use_kernel` / `kernel_interpret` route placement
-    scoring through the fused Pallas kernel (bitwise-identical results;
-    see `placement.place_in_row`)."""
+    `_fill_phase`).  `use_kernel` / `kernel_interpret` route placement's
+    line-up power feasibility through the Pallas kernel (bitwise-identical
+    results; see `placement.place_in_row`)."""
     ka, kb = jax.random.split(key)
     res_a = _fill_phase(jt, topo_init, trace_a, policy, ka, with_pods,
                         split_pods, pod_windows[0], cluster_starts[0],

@@ -283,9 +283,9 @@ def _sharded_sweep_jit(jt, ft, idx, valid, idx_pod, valid_pod, policy, seed,
                            exact_quantiles=exact_quantiles,
                            quantile_bins=quantile_bins)
     spec = shax.batch_spec()
-    sharded = shax.shard_map(jax.vmap(fn), mesh=mesh,
-                             in_specs=(spec,) * 10, out_specs=spec,
-                             check_vma=False)
+    sharded = jax.shard_map(jax.vmap(fn), mesh=mesh,
+                            in_specs=(spec,) * 10, out_specs=spec,
+                            check_vma=False)
     return sharded(jt, ft, idx, valid, idx_pod, valid_pod, policy, seed,
                    h_cap, n_real)
 
@@ -521,8 +521,8 @@ def sweep(axes: SweepAxes, harvest: bool = True, mature_months: int = 12,
             the stage).
         metric_year: serving-deployment year for the metric stage
             (default: each envelope's `end_year`).
-        use_kernel: route placement scoring through the fused Pallas
-            kernel (static; bitwise-identical results).  `None` = backend
+        use_kernel: route placement's line-up power feasibility through
+            the Pallas kernel (static; bitwise-identical results).  `None` = backend
             default (`placement.default_use_kernel`: TPU on, CPU off).
         kernel_interpret: run the kernel in Pallas interpret mode (CPU
             CI fallback; only meaningful with the kernel path on).
@@ -647,6 +647,9 @@ def sharded_sweep(axes: SweepAxes, harvest: bool = True,
     out = outs[0] if len(outs) == 1 else \
         jax.tree.map(lambda *xs: jnp.concatenate(xs), *outs)
     if B_pad != B:
-        out = jax.tree.map(lambda x: x[:B], out)
+        # drop the replicas on the host: slicing a mesh-sharded array
+        # needs an explicit out_sharding, and `_finalize` copies the
+        # outputs to the host anyway
+        out = jax.tree.map(lambda x: np.asarray(x)[:B], out)
     return _finalize(out, axes, months, topos, X_pad, mature_months,
                      models=models, metric_year=metric_year)

@@ -22,14 +22,14 @@ def _require_f32(name, x):
     x = jnp.asarray(x)
     if x.dtype == jnp.float64:
         raise TypeError(
-            f"score_rows: `{name}` is float64; the placement-score kernel "
+            f"feasible_rows: `{name}` is float64; the placement-score kernel "
             "computes in float32 (see module docstring). Cast inputs to "
             "float32 explicitly before calling.")
     return x.astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def score_rows(jt_row_feeds, jt_row_nfeeds, jt_row_cap_kw, lineup_ha,
+def feasible_rows(jt_row_feeds, jt_row_nfeeds, jt_row_cap_kw, lineup_ha,
                lineup_tot, lineup_cap, row_load_kw, p_dep, ha_frac,
                is_ha, is_block, block_r: int = 128,
                interpret: bool = False):
@@ -39,7 +39,7 @@ def score_rows(jt_row_feeds, jt_row_nfeeds, jt_row_cap_kw, lineup_ha,
     `hd_index[:K]`, with the other row arrays gathered to match) — the
     kernel itself is agnostic to row identity.  `is_ha`/`is_block` are
     0/1 flags (traced; deployment tier and topology family).  Returns
-    (feas [R] bool, score [R] f32; infeasible rows score `kernel.BIG`).
+    feas [R] bool: the line-up power condition AND the row power fit.
     """
     jt_row_feeds = jnp.asarray(jt_row_feeds, jnp.int32)
     jt_row_nfeeds = jnp.asarray(jt_row_nfeeds, jnp.int32)
@@ -59,7 +59,7 @@ def score_rows(jt_row_feeds, jt_row_nfeeds, jt_row_cap_kw, lineup_ha,
     params = jnp.stack([p_dep, ha_frac,
                         jnp.asarray(is_ha, jnp.float32).reshape(()),
                         jnp.asarray(is_block, jnp.float32).reshape(())])
-    feas, score = placement_score(
+    feas = placement_score(
         loads_ha, loads_tot, caps, valid, jt_row_nfeeds, row_load_kw,
         jt_row_cap_kw, params, block_r=block_r, interpret=interpret)
-    return feas > 0, score
+    return feas > 0

@@ -3,16 +3,15 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-BIG = 1e30
 
-
-def reference_score(loads_ha, loads_tot, caps, valid, nf, row_load, row_cap,
-                    params):
+def reference_feasible(loads_ha, loads_tot, caps, valid, nf, row_load,
+                       row_cap, params):
     """Pure-jnp mirror of `kernel.placement_score` on one [R, F] block.
 
     Same argument convention as the kernel (all f32; params =
     [p_dep, ha_frac, is_ha, is_block]); no padding/tiling — this is the
-    bitwise ground truth the Pallas path is tested against.
+    bitwise ground truth the Pallas path is tested against.  Returns
+    feas [R] f32 0/1.
     """
     loads_ha = loads_ha.astype(jnp.float32)
     loads_tot = loads_tot.astype(jnp.float32)
@@ -31,9 +30,4 @@ def reference_score(loads_ha, loads_tot, caps, valid, nf, row_load, row_cap,
     per_feed = jnp.where(is_block > 0, block_ok, dist_ok)
     power_ok = jnp.all(per_feed | (valid <= 0), axis=-1)
     fits = row_load + p_dep <= row_cap + 1e-4
-    feas = (power_ok & fits).astype(jnp.float32)
-
-    s = share[:, None] / jnp.maximum(caps, 1.0)
-    lhat = jnp.where(is_ha > 0, loads_ha, loads_tot) / jnp.maximum(caps, 1.0)
-    var = jnp.sum(valid * (2.0 * lhat * s + s * s), axis=-1)
-    return feas, jnp.where(feas > 0, var, BIG)
+    return (power_ok & fits).astype(jnp.float32)

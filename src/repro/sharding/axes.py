@@ -12,36 +12,16 @@ grids over a named 2-D (`CONFIG_AXIS` × `TRIAL_AXIS`) mesh (`sweep_mesh`)
 whose PartitionSpecs come from the `SWEEP_RULES` logical-axis table via
 `spec_for` (`batch_spec` for flat batches, `grid_spec` for [B, T] trial
 grids; the default (D, 1) shape reproduces the historical 1-D
-`config_mesh` layout bitwise).  The version-portable `shard_map` wrapper
-exported here is the one entry point the rest of the codebase uses.
+`config_mesh` layout bitwise).
 """
 from __future__ import annotations
 
 import contextlib
-import inspect
 import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                  # jax ≥ 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                   # jax ≤ 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep → check_vma in jax 0.7
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-portable `shard_map` (top-level vs experimental import,
-    check_rep/check_vma kwarg rename)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
-
 
 AxisVal = Union[None, str, Tuple[str, ...]]
 Rules = Dict[str, AxisVal]

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.axes import STAGE_AXIS, shard_map
+from repro.sharding.axes import STAGE_AXIS
 
 
 def pipeline(fn_stage: Callable, mesh: Mesh, stage_axis: str = STAGE_AXIS,
@@ -75,8 +75,8 @@ def pipeline(fn_stage: Callable, mesh: Mesh, stage_axis: str = STAGE_AXIS,
     def apply(stage_params, x):
         in_specs = (jax.tree.map(lambda _: P(stage_axis), stage_params),
                     P())
-        f = shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                      out_specs=P(), check_vma=False)
+        f = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                          out_specs=P(), check_vma=False)
         return f(stage_params, x)
 
     return apply

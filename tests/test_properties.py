@@ -96,11 +96,11 @@ def test_shape_bytes(dtype, dims):
        st.booleans(), st.integers(0, 2 ** 16))
 def test_score_rows_matches_reference(R, F, p_dep, ha_frac, is_ha,
                                       is_block, seed):
-    """Property: the Pallas `score_rows` path (interpret mode, padded to
-    block_r tiles) agrees with the pure-jnp `reference_score` oracle on
-    random feed maps / loads — feasibility bitwise, scores to f32 ulps."""
-    from repro.kernels.placement_score.ops import score_rows
-    from repro.kernels.placement_score.ref import reference_score
+    """Property: the Pallas `feasible_rows` path (interpret mode, padded
+    to block_r tiles) agrees bitwise with the pure-jnp
+    `reference_feasible` oracle on random feed maps / loads."""
+    from repro.kernels.placement_score.ops import feasible_rows
+    from repro.kernels.placement_score.ref import reference_feasible
     rng = np.random.default_rng(seed)
     X = 6
     feeds = np.where(rng.random((R, F)) < 0.25, -1,
@@ -111,21 +111,19 @@ def test_score_rows_matches_reference(R, F, p_dep, ha_frac, is_ha,
     caps = np.full((X,), 2500.0, np.float32)
     row_cap = rng.uniform(400, 900, R).astype(np.float32)
     row_load = rng.uniform(0, 500, R).astype(np.float32)
-    feas_k, score_k = score_rows(feeds, nfeeds, row_cap, ha, tot, caps,
-                                 row_load, p_dep, ha_frac, is_ha, is_block,
-                                 block_r=16, interpret=True)
+    feas_k = feasible_rows(feeds, nfeeds, row_cap, ha, tot, caps,
+                           row_load, p_dep, ha_frac, is_ha, is_block,
+                           block_r=128, interpret=True)
     safe = np.where(feeds >= 0, feeds, 0)
     valid = (feeds >= 0).astype(np.float32)
     params = jnp.array([p_dep, ha_frac, float(is_ha), float(is_block)],
                        jnp.float32)
-    feas_r, score_r = reference_score(
+    feas_r = reference_feasible(
         jnp.asarray(ha[safe]), jnp.asarray(tot[safe]),
         jnp.asarray(caps[safe]), jnp.asarray(valid), jnp.asarray(nfeeds),
         jnp.asarray(row_load), jnp.asarray(row_cap), params)
     np.testing.assert_array_equal(np.asarray(feas_k),
                                   np.asarray(feas_r) > 0)
-    np.testing.assert_allclose(np.asarray(score_k), np.asarray(score_r),
-                               rtol=1e-6)
 
 
 @settings(max_examples=15, deadline=None)

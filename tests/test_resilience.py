@@ -30,9 +30,11 @@ from repro.core import projections as proj  # noqa: E402
 from repro.core.arrivals import EnvelopeSpec  # noqa: E402
 from repro.core.hierarchy import SweepValidationError  # noqa: E402
 from repro.core.mc_sweep import MCAxes, mc_sweep  # noqa: E402
-from repro.core.resilience import (RUN_MANIFEST, FaultPlan,  # noqa: E402
-                                   InjectedCrash, ResumeMismatchError,
-                                   resilient_mc_sweep, resilient_sweep)
+from repro.core import resilience  # noqa: E402
+from repro.core.resilience import (RUN_MANIFEST, CompileError,  # noqa: E402
+                                   FaultPlan, InjectedCrash,
+                                   ResumeMismatchError, resilient_mc_sweep,
+                                   resilient_sweep)
 from repro.core.sweep import SweepAxes, sweep  # noqa: E402
 from repro.runtime.fault import Backoff  # noqa: E402
 
@@ -258,6 +260,37 @@ class TestQuarantine:
         keep = [i for i in range(8) if i != 5]
         _assert_bitwise(res, base8, SWEEP_FIELDS, rows=keep)
         assert np.isnan(res.final_deployed_mw[5])
+
+
+class TestCompileError:
+    @pytest.mark.parametrize("front", ["sweep", "mc"])
+    def test_compile_error_raises_and_quarantines_nothing(
+            self, front, axes8, mc_axes3, tmp_path, monkeypatch):
+        """A program the compiler refuses is not a poisoned
+        configuration.  Here the kernel path is lowered without interpret
+        mode for the CPU backend, which Pallas cannot compile: the
+        executor raises `CompileError` at the first range, before any
+        dispatch, without retrying, bisecting, quarantining or
+        committing a chunk."""
+        compiles = []
+        real = resilience._RangeEval.compile
+
+        def counting(self, lo, hi):
+            compiles.append((lo, hi))
+            return real(self, lo, hi)
+
+        monkeypatch.setattr(resilience._RangeEval, "compile", counting)
+        ck = str(tmp_path)
+        kw = dict(chunk_size=2, checkpoint_dir=ck, backoff=NO_WAIT,
+                  use_kernel=True, kernel_interpret=False)
+        with pytest.raises(CompileError) as err:
+            if front == "sweep":
+                resilient_sweep(axes8, **kw)
+            else:
+                resilient_mc_sweep(mc_axes3, **kw, **MC_KW)
+        assert err.value.__cause__ is not None
+        assert compiles == [(0, 2)]
+        assert not [n for n in os.listdir(ck) if n.startswith("step_")]
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,7 @@ registry.rule(
     "RL401", "float64-in-kernel-path",
     "kernel-reachable modules must not create float64 arrays: the "
     "placement-score kernel computes in float32 and its ops wrapper "
-    "rejects x64 inputs (score_rows contract)")
+    "rejects x64 inputs (feasible_rows contract)")
 
 _F64 = {"numpy.float64", "jax.numpy.float64"}
 
