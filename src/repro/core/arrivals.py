@@ -16,6 +16,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.runtime import spans
+
 from . import projections as proj
 from .resources import CLASS_COMPUTE, CLASS_GPU, CLASS_STORAGE, TIER_HA, TIER_LA
 
@@ -312,6 +314,7 @@ def _snap_refresh_waves(t: Trace, cycle_m: int) -> Trace:
     return t
 
 
+@spans.spanned("repro.arrivals.fleet_trace", events=len)
 def generate_fleet_trace(env: EnvelopeSpec, seed: int = 0) -> Trace:
     """Multi-year deployment trace over the buildout horizon (§5.1).
 
@@ -427,6 +430,8 @@ class TraceBatch:
         return int(np.asarray(self.n_racks)[pods].max()) if pods.any() else 1
 
 
+@spans.spanned("repro.arrivals.mixed_traces",
+               events=lambda batch: batch.month.size)
 def sample_mixed_traces(n_trials: int, n_events: int, year: int = 2028,
                         scenario: str = proj.MED, seed: int = 0,
                         gpu_power_share: float = 0.6,

@@ -463,9 +463,10 @@ def simulate_lifecycle(jt: JaxTopology, ft: FleetTrace, idx, valid,
         if exact_quantiles:
             ys = (hs_m, act_month)
         else:
-            ys = qt.hist_masked_quantiles(
-                hs_m, _mature_mask(act_month, m, mature_months),
-                (50.0, 90.0), n_bins=n_bins)
+            with jax.named_scope("repro.fleet.month_stats"):
+                ys = qt.hist_masked_quantiles(
+                    hs_m, _mature_mask(act_month, m, mature_months),
+                    (50.0, 90.0), n_bins=n_bins)
         return carry, (n_active, pl.deployed_kw(state)) + ys
 
     carry0 = (state, reg_rows, reg_counts, placed, harvested, removed,
@@ -483,8 +484,9 @@ def simulate_lifecycle(jt: JaxTopology, ft: FleetTrace, idx, valid,
             return _masked_percentiles(
                 hs, _mature_mask(am, m, mature_months), (50.0, 90.0))
 
-        p50, p90 = jax.vmap(stats)(y3, y4,
-                                   jnp.arange(M, dtype=jnp.int32))
+        with jax.named_scope("repro.fleet.month_stats"):
+            p50, p90 = jax.vmap(stats)(y3, y4,
+                                       jnp.arange(M, dtype=jnp.int32))
     else:
         p50, p90 = y3, y4
 

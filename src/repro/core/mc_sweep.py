@@ -44,6 +44,7 @@ from .hierarchy import (DesignSpec, HallTopology, SweepValidationError,
                         build_topology)
 from .placement import DEFAULT_POLICY, POLICY_NAMES, JaxTopology
 from .singlehall import TraceArrays, run_trial
+from repro.runtime import spans
 from repro.sharding import axes as shax
 from .sweep import _broadcast
 
@@ -328,6 +329,7 @@ def _pod_geometry(batches) -> Tuple[int, int]:
     return int(counts.max()), int(counts.min())
 
 
+@spans.spanned("repro.mc_sweep.prepare")
 def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
                 scenario: str, gpu_power_share: float, pod_racks: int,
                 quantum_racks: int, la_fraction: float,
@@ -343,13 +345,15 @@ def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
     (`sample_mixed_traces(phase=1)`); the historical `seed + 1` refill
     made a configuration seeded `s` share its refill trace bitwise with
     configuration `s+1`'s fill trace — correlated trials across
-    adjacent-seed grid points."""
+    adjacent-seed grid points.
+
+    Counts on its span: trace `events` (fill and refill), padded `rows`
+    and the stacked inputs' `h2d_bytes`."""
     axes.validate()          # precise SweepValidationErrors, pre-compile
     B = len(axes)
     R_pad = max(d.n_rows for d in axes.designs)
     X_pad = max(d.n_lineups for d in axes.designs)
     staged = [_staged_topology(d, R_pad, X_pad) for d in axes.designs]
-    jt = jax.tree.map(lambda *xs: jnp.stack(xs), *[s[1] for s in staged])
 
     E_b = refill_events or max(200, n_events // 3)
     share = 1.0 if single_sku_gpu else gpu_power_share
@@ -381,13 +385,21 @@ def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
             pod_scan_len=min(max(t.max_pod_racks for t in tas + tbs),
                              pl.MAX_POD_RACKS),
             hd_scan=max(s[0].n_hd_rows for s in staged))
-    ta, tb = stack(tas), stack(tbs)
-    keys = jnp.stack([jax.random.split(jax.random.PRNGKey(s), n_trials)
-                      for s in axes.seeds])
-    policy = jnp.asarray(axes.policies, jnp.int32)
-    return (jt, ta, tb, keys, policy), statics
+    with spans.span("repro.mc_sweep.prepare.stage"):
+        jt = jax.tree.map(lambda *xs: jnp.stack(xs),
+                          *[s[1] for s in staged])
+        ta, tb = stack(tas), stack(tbs)
+        keys = jnp.stack([jax.random.split(jax.random.PRNGKey(s), n_trials)
+                          for s in axes.seeds])
+        policy = jnp.asarray(axes.policies, jnp.int32)
+    args = (jt, ta, tb, keys, policy)
+    spans.count("events", B * n_trials * (n_events + E_b))
+    spans.count("rows", B * R_pad)
+    spans.count("h2d_bytes", sum(x.nbytes for x in jax.tree.leaves(args)))
+    return args, statics
 
 
+@spans.spanned("repro.mc_sweep.finalize")
 def _mc_finalize(out, axes: MCAxes, models=None, year: int = 2028,
                  scenario: str = proj.MED, gpu_share: float = 1.0,
                  pod_racks: int = 1) -> MCResult:
@@ -399,14 +411,15 @@ def _mc_finalize(out, axes: MCAxes, models=None, year: int = 2028,
     if models:
         # one serving deployment for the whole call (year/scenario/pod size
         # are call-level), so the metric stage is a single [1, Mdl] grid
-        dep = tp.serving_deployment(year, scenario, pod_racks)
-        tpw = np.asarray(tp.tps_per_watt_grid(models, [dep]))[0]  # [Mdl]
-        capex = np.array([cost.hall_capex(d) for d in axes.designs])
-        delivered = (deployed * 1e3 * gpu_share)[..., None] * tpw
-        with np.errstate(divide="ignore", invalid="ignore"):
-            tps_per_pw = delivered / (provisioned[:, None, None] * 1e6)
-            dpt = np.where(delivered > 0,
-                           capex[:, None, None] / delivered, np.nan)
+        with spans.span("repro.mc_sweep.finalize.metrics"):
+            dep = tp.serving_deployment(year, scenario, pod_racks)
+            tpw = np.asarray(tp.tps_per_watt_grid(models, [dep]))[0]  # [Mdl]
+            capex = np.array([cost.hall_capex(d) for d in axes.designs])
+            delivered = (deployed * 1e3 * gpu_share)[..., None] * tpw
+            with np.errstate(divide="ignore", invalid="ignore"):
+                tps_per_pw = delivered / (provisioned[:, None, None] * 1e6)
+                dpt = np.where(delivered > 0,
+                               capex[:, None, None] / delivered, np.nan)
     else:
         B, T = deployed.shape
         delivered = np.zeros((B, T, 0))
@@ -481,18 +494,23 @@ def mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
         kernel_interpret: run the kernel in Pallas interpret mode (the
             CPU CI fallback; only meaningful with `use_kernel=True`).
     """
-    args, statics = _mc_prepare(axes, n_trials, n_events, year, scenario,
-                                gpu_power_share, pod_racks,
-                                quantum_racks, la_fraction,
-                                single_sku_gpu, refill_events,
-                                legacy_pod_cond)
-    out = _mc_sweep_jit(*args, harvest=harvest,
-                        use_kernel=pl.resolve_use_kernel(use_kernel),
-                        kernel_interpret=kernel_interpret, **statics)
-    return _mc_finalize(out, axes, models=models, year=year,
-                        scenario=scenario,
-                        gpu_share=1.0 if single_sku_gpu else gpu_power_share,
-                        pod_racks=pod_racks)
+    with spans.span("repro.mc_sweep", configs=len(axes),
+                    trials=len(axes) * n_trials):
+        args, statics = _mc_prepare(axes, n_trials, n_events, year,
+                                    scenario, gpu_power_share, pod_racks,
+                                    quantum_racks, la_fraction,
+                                    single_sku_gpu, refill_events,
+                                    legacy_pod_cond)
+        with spans.span("repro.mc_sweep.dispatch"):
+            out = _mc_sweep_jit(*args, harvest=harvest,
+                                use_kernel=pl.resolve_use_kernel(use_kernel),
+                                kernel_interpret=kernel_interpret, **statics)
+        with spans.span("repro.mc_sweep.wait"):
+            out = jax.block_until_ready(out)
+        return _mc_finalize(
+            out, axes, models=models, year=year, scenario=scenario,
+            gpu_share=1.0 if single_sku_gpu else gpu_power_share,
+            pod_racks=pod_racks)
 
 
 def sharded_mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
@@ -544,65 +562,77 @@ def sharded_mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
     if len(devs) <= 1 or B * T == 1:
         return mc_sweep(axes, **kw)
 
-    (jt, ta, tb, keys, policy), statics = _mc_prepare(
-        axes, n_trials, n_events, year, scenario, gpu_power_share,
-        pod_racks, quantum_racks, la_fraction, single_sku_gpu,
-        refill_events, legacy_pod_cond)
-    mesh = shax.sweep_mesh(devs, mesh_shape)
-    dc, dt = mesh.devices.shape
+    with spans.span("repro.mc_sweep", configs=B, trials=B * T):
+        (jt, ta, tb, keys, policy), statics = _mc_prepare(
+            axes, n_trials, n_events, year, scenario, gpu_power_share,
+            pod_racks, quantum_racks, la_fraction, single_sku_gpu,
+            refill_events, legacy_pod_cond)
+        mesh = shax.sweep_mesh(devs, mesh_shape)
+        dc, dt = mesh.devices.shape
 
-    if dt > 1:
-        # ---- 2-D grid path: pad B → ·dc and T → ·dt, ship [B] leaves
-        # config-sharded and [B, T] leaves grid-sharded ----
-        B_pad, T_pad = -(-B // dc) * dc, -(-T // dt) * dt
+        if dt > 1:
+            # ---- 2-D grid path: pad B → ·dc and T → ·dt, ship [B] leaves
+            # config-sharded and [B, T] leaves grid-sharded ----
+            with spans.span("repro.mc_sweep.dispatch"):
+                B_pad, T_pad = -(-B // dc) * dc, -(-T // dt) * dt
 
-        def pad_axis(x, n, axis):
-            if x.shape[axis] == n:
-                return x
-            take = jnp.take(x, jnp.zeros((n - x.shape[axis],), jnp.int32),
-                            axis=axis)
-            return jnp.concatenate([x, take], axis=axis)
+                def pad_axis(x, n, axis):
+                    if x.shape[axis] == n:
+                        return x
+                    take = jnp.take(
+                        x, jnp.zeros((n - x.shape[axis],), jnp.int32),
+                        axis=axis)
+                    return jnp.concatenate([x, take], axis=axis)
 
-        cfg_leaves = jax.tree.map(lambda x: pad_axis(x, B_pad, 0),
-                                  (jt, policy))
-        grid_leaves = jax.tree.map(
-            lambda x: pad_axis(pad_axis(x, B_pad, 0), T_pad, 1),
-            (ta, tb, keys))
-        cfg_leaves = jax.device_put(
-            cfg_leaves, NamedSharding(mesh, shax.config_spec()))
-        ta, tb, keys = jax.device_put(
-            grid_leaves, NamedSharding(mesh, shax.grid_spec()))
-        out = _mc_sharded2d_jit(cfg_leaves[0], ta, tb, keys, cfg_leaves[1],
-                                harvest=harvest, mesh=mesh,
-                                use_kernel=pl.resolve_use_kernel(use_kernel),
-                                kernel_interpret=kernel_interpret, **statics)
-        # drop padding on the host (see `sweep.sharded_sweep`)
-        out = jax.tree.map(lambda x: np.asarray(x)[:B, :T], out)
-    else:
-        # ---- flat path: repeat per-config leaves per trial and shard
-        # the [B·T] axis over the whole mesh ----
-        jt = jax.tree.map(lambda x: jnp.repeat(x, T, axis=0), jt)
-        policy = jnp.repeat(policy, T)
-        flat = jax.tree.map(lambda x: x.reshape((B * T,) + x.shape[2:]),
-                            (ta, tb, keys))
-        args = (jt,) + flat + (policy,)
+                cfg_leaves = jax.tree.map(lambda x: pad_axis(x, B_pad, 0),
+                                          (jt, policy))
+                grid_leaves = jax.tree.map(
+                    lambda x: pad_axis(pad_axis(x, B_pad, 0), T_pad, 1),
+                    (ta, tb, keys))
+                cfg_leaves = jax.device_put(
+                    cfg_leaves, NamedSharding(mesh, shax.config_spec()))
+                ta, tb, keys = jax.device_put(
+                    grid_leaves, NamedSharding(mesh, shax.grid_spec()))
+                out = _mc_sharded2d_jit(
+                    cfg_leaves[0], ta, tb, keys, cfg_leaves[1],
+                    harvest=harvest, mesh=mesh,
+                    use_kernel=pl.resolve_use_kernel(use_kernel),
+                    kernel_interpret=kernel_interpret, **statics)
+            with spans.span("repro.mc_sweep.wait"):
+                out = jax.block_until_ready(out)
+                # drop padding on the host (see `sweep.sharded_sweep`)
+                out = jax.tree.map(lambda x: np.asarray(x)[:B, :T], out)
+        else:
+            # ---- flat path: repeat per-config leaves per trial and shard
+            # the [B·T] axis over the whole mesh ----
+            with spans.span("repro.mc_sweep.dispatch"):
+                jt = jax.tree.map(lambda x: jnp.repeat(x, T, axis=0), jt)
+                policy = jnp.repeat(policy, T)
+                flat = jax.tree.map(
+                    lambda x: x.reshape((B * T,) + x.shape[2:]),
+                    (ta, tb, keys))
+                args = (jt,) + flat + (policy,)
 
-        D = len(devs)
-        N_pad = -(-B * T // D) * D
-        if N_pad != B * T:
-            def pad(x):
-                fill = jnp.broadcast_to(x[:1],
-                                        (N_pad - B * T,) + x.shape[1:])
-                return jnp.concatenate([x, fill])
-            args = jax.tree.map(pad, args)
+                D = len(devs)
+                N_pad = -(-B * T // D) * D
+                if N_pad != B * T:
+                    def pad(x):
+                        fill = jnp.broadcast_to(
+                            x[:1], (N_pad - B * T,) + x.shape[1:])
+                        return jnp.concatenate([x, fill])
+                    args = jax.tree.map(pad, args)
 
-        args = jax.device_put(args, NamedSharding(mesh, shax.batch_spec()))
-        out = _mc_sharded_jit(*args, harvest=harvest, mesh=mesh,
-                              use_kernel=pl.resolve_use_kernel(use_kernel),
-                              kernel_interpret=kernel_interpret, **statics)
-        out = jax.tree.map(lambda x: np.asarray(x)[:B * T].reshape(
-            (B, T) + x.shape[1:]), out)
-    return _mc_finalize(out, axes, models=models, year=year,
-                        scenario=scenario,
-                        gpu_share=1.0 if single_sku_gpu else gpu_power_share,
-                        pod_racks=pod_racks)
+                args = jax.device_put(args,
+                                      NamedSharding(mesh, shax.batch_spec()))
+                out = _mc_sharded_jit(
+                    *args, harvest=harvest, mesh=mesh,
+                    use_kernel=pl.resolve_use_kernel(use_kernel),
+                    kernel_interpret=kernel_interpret, **statics)
+            with spans.span("repro.mc_sweep.wait"):
+                out = jax.block_until_ready(out)
+                out = jax.tree.map(lambda x: np.asarray(x)[:B * T].reshape(
+                    (B, T) + x.shape[1:]), out)
+        return _mc_finalize(
+            out, axes, models=models, year=year, scenario=scenario,
+            gpu_share=1.0 if single_sku_gpu else gpu_power_share,
+            pod_racks=pod_racks)

@@ -21,6 +21,10 @@ Placement policies (paper §4.2, Fig. 7): random, round-robin, min-waste
 (best fit), variance-minimization (default; minimizes post-placement UPS
 load imbalance — implemented via the exact sufficient-statistic reduction:
 argmin Var(loads') ≡ argmin Σ_{p∈feeds} [2·l̂_p·s + s²], s = P/(k·C)).
+
+The hot steps run under `jax.named_scope("repro.placement.<step>")`, so
+their device ops carry the step's name in a profile (docs/architecture.md,
+"Tracing a sweep").
 """
 from __future__ import annotations
 
@@ -157,6 +161,7 @@ def _tree_where(pred, a, b):
     return jax.tree.map(lambda x, y: jnp.where(pred, x, y), a, b)
 
 
+@jax.named_scope("repro.placement.gather_feeds")
 def _gather_feeds(jt: JaxTopology, state: HallState, row_feeds=None):
     idx = jt.row_feeds if row_feeds is None else row_feeds   # [R|K, F]
     valid = idx >= 0
@@ -164,6 +169,7 @@ def _gather_feeds(jt: JaxTopology, state: HallState, row_feeds=None):
     return valid, safe, jt.lineup_cap[safe], state.lineup_ha[safe], state.lineup_tot[safe]
 
 
+@jax.named_scope("repro.placement.gather_feeds")
 def _row_view(jt: JaxTopology, state: HallState, rows):
     """Row-axis arrays, gathered at `rows` when given (compacted view).
 
@@ -211,6 +217,7 @@ def _kernel_feasible(jt: JaxTopology, state: HallState, dep: Deployment,
         interpret=interpret)
 
 
+@jax.named_scope("repro.placement.row_feasible")
 def row_feasible(jt: JaxTopology, state: HallState, dep: Deployment,
                  n_in_row, rows=None, use_kernel: bool = False,
                  interpret: bool = False) -> jax.Array:
@@ -251,6 +258,7 @@ def row_feasible(jt: JaxTopology, state: HallState, dep: Deployment,
     return extra & power_ok
 
 
+@jax.named_scope("repro.placement.row_scores")
 def row_scores(jt: JaxTopology, state: HallState, dep: Deployment,
                n_in_row, policy, key, rows=None) -> jax.Array:
     """Per-row placement score (lower is better).  With `rows`, scores are
@@ -364,6 +372,7 @@ def place_cluster_in_row(jt: JaxTopology, state: HallState,
     return st, ok, rows, counts, row
 
 
+@jax.named_scope("repro.placement.place_pod")
 def _place_pod(jt: JaxTopology, state: HallState, dep: Deployment,
                policy, key, row_active, max_racks: int = MAX_POD_RACKS,
                hd_scan: int | None = None, use_kernel: bool = False,
@@ -434,6 +443,7 @@ def place(jt: JaxTopology, state: HallState, dep: Deployment, policy, key,
     )
 
 
+@jax.named_scope("repro.placement.release_bulk")
 def release_bulk(jt: JaxTopology, state: HallState, rows, counts, rack_kw,
                  is_gpu, tier, fraction) -> HallState:
     """Release `fraction` of the demand recorded by a batch of placement
@@ -501,6 +511,7 @@ def remove_from_row(jt: JaxTopology, state: HallState, rack_kw, is_gpu,
 # Stranding metrics (paper §4.3).
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("repro.placement.stranding")
 def lineup_stranding(jt: JaxTopology, state: HallState) -> jax.Array:
     """Per-line-up unused fraction of *effective HA* capacity.  At
     saturation (placements failing) this is the stranded fraction."""
@@ -509,6 +520,7 @@ def lineup_stranding(jt: JaxTopology, state: HallState) -> jax.Array:
     return jnp.where(jt.lineup_is_active, jnp.clip(frac, 0.0, 1.0), 0.0)
 
 
+@jax.named_scope("repro.placement.stranding")
 def hall_stranding(jt: JaxTopology, state: HallState) -> jax.Array:
     """Per-hall unused fraction of effective HA capacity, shape [H].
 

@@ -54,6 +54,7 @@ from .fleet import (FleetConfig, FleetResult, FleetTrace, _auto_halls,
                     make_fleet_result, simulate_lifecycle)
 from .hierarchy import DesignSpec, SweepValidationError, build_topology
 from .placement import DEFAULT_POLICY, MAX_POD_RACKS, POLICY_NAMES
+from repro.runtime import spans
 from repro.sharding import axes as shax
 
 
@@ -290,6 +291,7 @@ def _sharded_sweep_jit(jt, ft, idx, valid, idx_pod, valid_pod, policy, seed,
                    h_cap, n_real)
 
 
+@spans.spanned("repro.sweep.prepare")
 def _prepare(axes: SweepAxes, n_halls_max: int,
              traces: Sequence[Trace] | None,
              legacy_pod_cond: bool = False):
@@ -316,6 +318,9 @@ def _prepare(axes: SweepAxes, n_halls_max: int,
     trim the pod rack scan / compacted HD row view.
     `legacy_pod_cond=True` windows all events together for the
     pre-split reference path (see `simulate_lifecycle`).
+
+    Counts on its span: real trace `events`, the monthly scan's
+    `event_slots`, padded hall `rows` and the stacked inputs' `h2d_bytes`.
     """
     axes.validate()          # precise SweepValidationErrors, pre-compile
     B = len(axes)
@@ -337,41 +342,50 @@ def _prepare(axes: SweepAxes, n_halls_max: int,
     H_max = bucket(max(h_caps), 4)
     R_pad = max(d.n_rows for d in axes.designs)
     X_pad = max(d.n_lineups for d in axes.designs)
-    topos = [build_topology(d, H_max, rows_per_hall=R_pad,
-                            lineups_per_hall=X_pad) for d in axes.designs]
-    jt = jax.tree.map(lambda *xs: jnp.stack(xs),
-                      *[pl.jax_topology(t) for t in topos])
+    with spans.span("repro.sweep.prepare.topology"):
+        topos = [build_topology(d, H_max, rows_per_hall=R_pad,
+                                lineups_per_hall=X_pad)
+                 for d in axes.designs]
+        jt = jax.tree.map(lambda *xs: jnp.stack(xs),
+                          *[pl.jax_topology(t) for t in topos])
 
-    E_max = bucket(max(len(t) for t in traces), 64)
-    ft = jax.tree.map(lambda *xs: jnp.stack(xs),
-                      *[FleetTrace.from_trace(t, pad_to=E_max,
-                                              pad_month=months)
-                        for t in traces])
-    with_pods = any(bool(np.asarray(t.is_pod).any()) for t in traces)
-    split = with_pods and not legacy_pod_cond
-    pod_sel = [np.asarray(t.is_pod) for t in traces]
-    e_max = bucket(max(_month_e_max(t, months,
-                                    select=~p if split else None)
-                       for t, p in zip(traces, pod_sel)), 4)
-    # pod windows stay exact (no bucket): a pod scan step costs ~16
-    # cluster steps (two 8-rack `_place_pod` scans), so one padded pod
-    # slot per month would erase most of the split-trace win; monthly
-    # pod counts are small and stable within a study, so the jit cache
-    # still carries across same-scale grids.
-    ep_max = (max(_month_e_max(t, months, select=p)
-                  for t, p in zip(traces, pod_sel)) if split else 1)
-    windows = [_event_windows(t, months, split, e_max=e_max, ep_max=ep_max,
-                              modulo=E_max) for t in traces]
-    idx = jnp.asarray(np.stack([w[0] for w in windows]))
-    valid = jnp.asarray(np.stack([w[1] for w in windows]))
-    idx_pod = jnp.asarray(np.stack([w[2] for w in windows]))
-    valid_pod = jnp.asarray(np.stack([w[3] for w in windows]))
+    with spans.span("repro.sweep.prepare.traces"):
+        E_max = bucket(max(len(t) for t in traces), 64)
+        ft = jax.tree.map(lambda *xs: jnp.stack(xs),
+                          *[FleetTrace.from_trace(t, pad_to=E_max,
+                                                  pad_month=months)
+                            for t in traces])
+        with_pods = any(bool(np.asarray(t.is_pod).any()) for t in traces)
+        split = with_pods and not legacy_pod_cond
+        pod_sel = [np.asarray(t.is_pod) for t in traces]
+        e_max = bucket(max(_month_e_max(t, months,
+                                        select=~p if split else None)
+                           for t, p in zip(traces, pod_sel)), 4)
+        # pod windows stay exact (no bucket): a pod scan step costs ~16
+        # cluster steps (two 8-rack `_place_pod` scans), so one padded pod
+        # slot per month would erase most of the split-trace win; monthly
+        # pod counts are small and stable within a study, so the jit cache
+        # still carries across same-scale grids.
+        ep_max = (max(_month_e_max(t, months, select=p)
+                      for t, p in zip(traces, pod_sel)) if split else 1)
+        windows = [_event_windows(t, months, split, e_max=e_max,
+                                  ep_max=ep_max, modulo=E_max)
+                   for t in traces]
+        idx = jnp.asarray(np.stack([w[0] for w in windows]))
+        valid = jnp.asarray(np.stack([w[1] for w in windows]))
+        idx_pod = jnp.asarray(np.stack([w[2] for w in windows]))
+        valid_pod = jnp.asarray(np.stack([w[3] for w in windows]))
 
     args = (jt, ft, idx, valid, idx_pod, valid_pod,
             jnp.asarray(axes.policies, jnp.int32),
             jnp.asarray(axes.seeds, jnp.int32),
             jnp.asarray(h_caps, jnp.int32),
             jnp.asarray([len(t) for t in traces], jnp.int32))
+    spans.count("events", sum(len(t) for t in traces))
+    spans.count("event_slots",
+                B * months * (e_max + (ep_max if split else 0)))
+    spans.count("rows", B * H_max * R_pad)
+    spans.count("h2d_bytes", sum(x.nbytes for x in jax.tree.leaves(args)))
     hd_scan = max(t.n_hd_rows for t in topos)
     return args, months, topos, X_pad, with_pods, _pod_scan_len(traces), \
         hd_scan
@@ -430,12 +444,17 @@ def _metric_stage(axes: SweepAxes, models, metric_year,
     return [m.name for m in models], delivered, tps_per_pw, dpt
 
 
+@spans.spanned("repro.sweep.finalize")
 def _finalize(out, axes: SweepAxes, months: int, topos, X_pad: int,
               mature_months: int, models=None,
               metric_year: int | None = None) -> SweepResult:
     """Host-side unpack of batched `SimOutputs` + cost model into a
-    `SweepResult` (shared by `sweep` and `sharded_sweep`)."""
+    `SweepResult` (shared by `sweep` and `sharded_sweep`).  Counts on
+    its span: `halls_built` and their designs' `rows_built`."""
     n_built = np.asarray(out.n_halls_built).astype(int)
+    spans.count("halls_built", int(n_built.sum()))
+    spans.count("rows_built", sum(int(n) * d.n_rows
+                                  for d, n in zip(axes.designs, n_built)))
     deployed_mw = np.asarray(out.final_deployed_kw) / 1e3
     initial = np.array([cost.initial_dollars_per_mw(d)
                         for d in axes.designs])
@@ -446,8 +465,9 @@ def _finalize(out, axes: SweepAxes, months: int, topos, X_pad: int,
                       for d, n in zip(axes.designs, n_built)])
     provisioned = np.array([int(n) * d.ha_capacity_kw / 1e3
                             for d, n in zip(axes.designs, n_built)])
-    names, delivered, tps_per_pw, dpt = _metric_stage(
-        axes, models, metric_year, deployed_mw, provisioned, capex)
+    with spans.span("repro.sweep.finalize.metrics"):
+        names, delivered, tps_per_pw, dpt = _metric_stage(
+            axes, models, metric_year, deployed_mw, provisioned, capex)
     return SweepResult(
         axes=axes,
         months=np.arange(months),
@@ -535,17 +555,23 @@ def sweep(axes: SweepAxes, harvest: bool = True, mature_months: int = 12,
         quantile_bins: streaming-histogram resolution (default
             `quantiles.DEFAULT_BINS` = 512); ignored when exact.
     """
-    args, months, topos, X_pad, with_pods, pod_len, hd_scan = _prepare(
-        axes, n_halls_max, traces, legacy_pod_cond)
-    out = _sweep_jit(*args, harvest=harvest, mature_months=mature_months,
-                     with_pods=with_pods, legacy_pod_cond=legacy_pod_cond,
-                     pod_scan_len=pod_len, hd_scan=hd_scan,
-                     use_kernel=pl.resolve_use_kernel(use_kernel),
-                     kernel_interpret=kernel_interpret,
-                     exact_quantiles=exact_quantiles,
-                     quantile_bins=quantile_bins)
-    return _finalize(out, axes, months, topos, X_pad, mature_months,
-                     models=models, metric_year=metric_year)
+    with spans.span("repro.sweep", configs=len(axes)):
+        args, months, topos, X_pad, with_pods, pod_len, hd_scan = _prepare(
+            axes, n_halls_max, traces, legacy_pod_cond)
+        with spans.span("repro.sweep.dispatch"):
+            out = _sweep_jit(*args, harvest=harvest,
+                             mature_months=mature_months,
+                             with_pods=with_pods,
+                             legacy_pod_cond=legacy_pod_cond,
+                             pod_scan_len=pod_len, hd_scan=hd_scan,
+                             use_kernel=pl.resolve_use_kernel(use_kernel),
+                             kernel_interpret=kernel_interpret,
+                             exact_quantiles=exact_quantiles,
+                             quantile_bins=quantile_bins)
+        with spans.span("repro.sweep.wait"):
+            out = jax.block_until_ready(out)
+        return _finalize(out, axes, months, topos, X_pad, mature_months,
+                         models=models, metric_year=metric_year)
 
 
 def sharded_sweep(axes: SweepAxes, harvest: bool = True,
@@ -613,43 +639,50 @@ def sharded_sweep(axes: SweepAxes, harvest: bool = True,
                      exact_quantiles=exact_quantiles,
                      quantile_bins=quantile_bins)
 
-    args, months, topos, X_pad, with_pods, pod_len, hd_scan = _prepare(
-        axes, n_halls_max, traces)
-    B, D = len(axes), len(devs)
-    C = -(-B // D) * D if chunk_size is None \
-        else max(-(-int(chunk_size) // D) * D, D)
-    B_pad = -(-B // C) * C
-    if B_pad != B:
-        def pad(x):
-            fill = jnp.broadcast_to(x[:1], (B_pad - B,) + x.shape[1:])
-            return jnp.concatenate([x, fill])
-        args = jax.tree.map(pad, args)
+    with spans.span("repro.sweep", configs=len(axes)):
+        args, months, topos, X_pad, with_pods, pod_len, hd_scan = _prepare(
+            axes, n_halls_max, traces)
+        B, D = len(axes), len(devs)
+        with spans.span("repro.sweep.dispatch"):
+            C = -(-B // D) * D if chunk_size is None \
+                else max(-(-int(chunk_size) // D) * D, D)
+            B_pad = -(-B // C) * C
+            if B_pad != B:
+                def pad(x):
+                    fill = jnp.broadcast_to(x[:1],
+                                            (B_pad - B,) + x.shape[1:])
+                    return jnp.concatenate([x, fill])
+                args = jax.tree.map(pad, args)
 
-    mesh = shax.sweep_mesh(devs, mesh_shape)
-    sharding = NamedSharding(mesh, shax.batch_spec())
-    kw = dict(harvest=harvest, mature_months=mature_months,
-              with_pods=with_pods, pod_scan_len=pod_len, hd_scan=hd_scan,
-              use_kernel=pl.resolve_use_kernel(use_kernel),
-              kernel_interpret=kernel_interpret,
-              exact_quantiles=exact_quantiles,
-              quantile_bins=quantile_bins, mesh=mesh)
-    outs = []
-    with warnings.catch_warnings():
-        # int topology/trace buffers can never alias the f32 output
-        # curves; XLA's per-buffer "donated but not usable" note is
-        # expected here, and the usable donations still land
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        for s in range(0, B_pad, C):
-            chunk = jax.device_put(
-                jax.tree.map(lambda x: x[s:s + C], args), sharding)
-            outs.append(_sharded_sweep_jit(*chunk, **kw))
-    out = outs[0] if len(outs) == 1 else \
-        jax.tree.map(lambda *xs: jnp.concatenate(xs), *outs)
-    if B_pad != B:
-        # drop the replicas on the host: slicing a mesh-sharded array
-        # needs an explicit out_sharding, and `_finalize` copies the
-        # outputs to the host anyway
-        out = jax.tree.map(lambda x: np.asarray(x)[:B], out)
-    return _finalize(out, axes, months, topos, X_pad, mature_months,
-                     models=models, metric_year=metric_year)
+            mesh = shax.sweep_mesh(devs, mesh_shape)
+            sharding = NamedSharding(mesh, shax.batch_spec())
+            kw = dict(harvest=harvest, mature_months=mature_months,
+                      with_pods=with_pods, pod_scan_len=pod_len,
+                      hd_scan=hd_scan,
+                      use_kernel=pl.resolve_use_kernel(use_kernel),
+                      kernel_interpret=kernel_interpret,
+                      exact_quantiles=exact_quantiles,
+                      quantile_bins=quantile_bins, mesh=mesh)
+            outs = []
+            with warnings.catch_warnings():
+                # int topology/trace buffers can never alias the f32
+                # output curves; XLA's per-buffer "donated but not
+                # usable" note is expected here, and the usable
+                # donations still land
+                warnings.filterwarnings(
+                    "ignore", message="Some donated buffers were not usable")
+                for s in range(0, B_pad, C):
+                    chunk = jax.device_put(
+                        jax.tree.map(lambda x: x[s:s + C], args), sharding)
+                    outs.append(_sharded_sweep_jit(*chunk, **kw))
+            out = outs[0] if len(outs) == 1 else \
+                jax.tree.map(lambda *xs: jnp.concatenate(xs), *outs)
+        with spans.span("repro.sweep.wait"):
+            out = jax.block_until_ready(out)
+            if B_pad != B:
+                # drop the replicas on the host: slicing a mesh-sharded
+                # array needs an explicit out_sharding, and `_finalize`
+                # copies the outputs to the host anyway
+                out = jax.tree.map(lambda x: np.asarray(x)[:B], out)
+        return _finalize(out, axes, months, topos, X_pad, mature_months,
+                         models=models, metric_year=metric_year)
