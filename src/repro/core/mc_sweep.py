@@ -347,8 +347,10 @@ def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
     configuration `s+1`'s fill trace — correlated trials across
     adjacent-seed grid points.
 
-    Counts on its span: trace `events` (fill and refill), padded `rows`
-    and the stacked inputs' `h2d_bytes`."""
+    Counts on its span: trace `events` (fill and refill), padded `rows`,
+    the stacked inputs' `h2d_bytes` and `hall_local_configs`, the
+    configurations whose topology takes the hall-local placement path
+    (`placement.JaxTopology`)."""
     axes.validate()          # precise SweepValidationErrors, pre-compile
     B = len(axes)
     R_pad = max(d.n_rows for d in axes.designs)
@@ -395,6 +397,8 @@ def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
     args = (jt, ta, tb, keys, policy)
     spans.count("events", B * n_trials * (n_events + E_b))
     spans.count("rows", B * R_pad)
+    spans.count("hall_local_configs",
+                sum(j.row_feeds_local is not None for _, j in staged))
     spans.count("h2d_bytes", sum(x.nbytes for x in jax.tree.leaves(args)))
     return args, statics
 

@@ -37,8 +37,7 @@ import numpy as np
 
 from .hierarchy import HallTopology, MAX_FEEDS
 from .resources import LIQ, N_RES, POWER, TIER_HA, rack_demand
-from ..kernels.placement_score.ops import (
-    feasible_rows as _kernel_feasible_rows)
+from ..kernels.placement_score.ops import feasible_rows_from_views
 
 # Policy ids (paper §4.2).
 POLICY_RANDOM, POLICY_ROUND_ROBIN, POLICY_MIN_WASTE, POLICY_VAR_MIN = 0, 1, 2, 3
@@ -72,7 +71,19 @@ def resolve_use_kernel(use_kernel) -> bool:
 
 
 class JaxTopology(NamedTuple):
-    """Device-resident mirror of `HallTopology`."""
+    """Device-resident mirror of `HallTopology`.
+
+    Tiled path: when the topology tiles one hall H times, as
+    `build_topology` builds every topology the sweeps run (hall h holds
+    rows ``[h·Rh, (h+1)·Rh)`` and line-ups ``[h·Xh, (h+1)·Xh)``, and its
+    rows' feeds are hall 0's plus ``h·Xh``), `jax_topology` sets
+    `row_feeds_local` and `row_feed_cap`.  The full-row placement step
+    then reads each row's feed state and hall state as hall-local views
+    (line-up arrays reshaped to ``[H, Xh]`` and selected at the local
+    table, hall arrays broadcast over ``[H, Rh]``) instead of gathering
+    at `row_feeds` / `row_hall`.  A view holds the one selected value, so
+    every result is bitwise the gather path's, which hand-built
+    topologies (both fields `None`) and row-subset (pod) scans keep."""
     row_cap: jax.Array      # [R, N_RES]
     row_feeds: jax.Array    # [R, MAX_FEEDS] int32
     row_nfeeds: jax.Array   # [R] int32
@@ -88,12 +99,45 @@ class JaxTopology(NamedTuple):
     hall_liq_cap: jax.Array  # [H]
     ha_frac: jax.Array      # scalar
     is_block: jax.Array     # scalar bool
+    row_feeds_local: jax.Array | None = None  # [Rh, MAX_FEEDS] int32 —
+                            # each feed's line-up within its hall (-1:
+                            # none); tiled topologies only
+    row_feed_cap: jax.Array | None = None     # [R, MAX_FEEDS] — each
+                            # feed's rating (0: none); tiled topologies only
+
+
+def _hall_tiling(topo: HallTopology) -> np.ndarray | None:
+    """Hall 0's row→feed table in hall-local line-up ids, `[Rh, F]`, if
+    `topo` tiles it over its halls (see `JaxTopology`); else None."""
+    H = topo.n_halls
+    feeds = np.asarray(topo.row_feeds)
+    R, X = feeds.shape[0], topo.lineup_cap.shape[0]
+    if R % H or X % H:
+        return None
+    Rh, Xh = R // H, X // H
+    halls = np.arange(H)
+    local = feeds[:Rh]
+    if np.any(local >= Xh) or np.any(
+            np.asarray(topo.row_hall) != np.repeat(halls, Rh)):
+        return None
+    tiled = np.where(local >= 0, local + (halls * Xh)[:, None, None], local)
+    if not np.array_equal(feeds.reshape(H, Rh, -1), tiled):
+        return None
+    return local.astype(np.int32)
 
 
 def jax_topology(topo: HallTopology) -> JaxTopology:
     # stable: HD rows keep their ascending id order, so a compacted argmin
     # tie-breaks exactly like the full-row argmin restricted to HD rows
     hd_index = np.argsort(~np.asarray(topo.row_is_hd), kind="stable")
+    local = _hall_tiling(topo)
+    tiled = {}
+    if local is not None:
+        feeds = np.asarray(topo.row_feeds)
+        cap = np.asarray(topo.lineup_cap)[np.maximum(feeds, 0)]
+        tiled = dict(row_feeds_local=jnp.asarray(local),
+                     row_feed_cap=jnp.asarray(
+                         np.where(feeds >= 0, cap, 0), jnp.float32))
     return JaxTopology(
         row_cap=jnp.asarray(topo.row_cap),
         row_feeds=jnp.asarray(topo.row_feeds),
@@ -108,6 +152,7 @@ def jax_topology(topo: HallTopology) -> JaxTopology:
         hall_liq_cap=jnp.asarray(topo.hall_liq_cap),
         ha_frac=jnp.asarray(topo.ha_frac, jnp.float32),
         is_block=jnp.asarray(topo.is_block),
+        **tiled,
     )
 
 
@@ -161,12 +206,39 @@ def _tree_where(pred, a, b):
     return jax.tree.map(lambda x, y: jnp.where(pred, x, y), a, b)
 
 
+def _tiled(jt: JaxTopology, rows) -> bool:
+    """Whether the step reads hall-local views (`JaxTopology`'s tiled
+    path): a tiled topology scanned over all of its rows."""
+    return rows is None and jt.row_feeds_local is not None
+
+
+def _hall_local(jt: JaxTopology, x) -> jax.Array:
+    """`x[row_feeds]` of a per-line-up array on the tiled path, [R, F]
+    with 0 where a row has no feed, gathering nothing: `x` reshaped to
+    [H, Xh] and selected at the hall-local feed table."""
+    local = jt.row_feeds_local                           # [Rh, F]
+    xh = x.reshape(jt.hall_liq_cap.shape[0], -1)         # [H, Xh]
+    view = jnp.zeros(xh.shape[:1] + local.shape, x.dtype)
+    for k in range(xh.shape[1]):
+        col = jax.lax.slice_in_dim(xh, k, k + 1, axis=1)    # [H, 1]
+        view = jnp.where(local == k, col.reshape(-1, 1, 1), view)
+    return view.reshape(-1, local.shape[1])
+
+
 @jax.named_scope("repro.placement.gather_feeds")
-def _gather_feeds(jt: JaxTopology, state: HallState, row_feeds=None):
-    idx = jt.row_feeds if row_feeds is None else row_feeds   # [R|K, F]
-    valid = idx >= 0
-    safe = jnp.where(valid, idx, 0)
-    return valid, safe, jt.lineup_cap[safe], state.lineup_ha[safe], state.lineup_tot[safe]
+def _feed_views(jt: JaxTopology, state: HallState, r_feeds, rows):
+    """(valid, cap, ha_load, tot_load), each [R|K, F]: the line-up state
+    behind every feed of the rows `_row_view` gives (`r_feeds`).  Hall-local
+    views on the tiled path, gathers at the feeds otherwise; both give
+    valid feeds bitwise the same values, and every consumer masks the
+    others."""
+    valid = r_feeds >= 0
+    if _tiled(jt, rows):
+        return (valid, jt.row_feed_cap, _hall_local(jt, state.lineup_ha),
+                _hall_local(jt, state.lineup_tot))
+    safe = jnp.where(valid, r_feeds, 0)
+    return (valid, jt.lineup_cap[safe], state.lineup_ha[safe],
+            state.lineup_tot[safe])
 
 
 @jax.named_scope("repro.placement.gather_feeds")
@@ -196,7 +268,14 @@ def _row_fits(jt: JaxTopology, state: HallState, dep: Deployment,
     r_cap, r_load, _, _, r_is_hd, r_hall = _row_view(jt, state, rows)
     fits_row = jnp.all(r_load + D[None, :] <= r_cap + 1e-4, axis=-1)
     hd_ok = jnp.where(dep.is_gpu, r_is_hd, True)
-    liq_ok = (state.hall_liq + D[LIQ])[r_hall] <= jt.hall_liq_cap[r_hall] + 1e-4
+    if _tiled(jt, rows):        # hall h's rows are [h·Rh, (h+1)·Rh)
+        liq_h = state.hall_liq + D[LIQ] <= jt.hall_liq_cap + 1e-4   # [H]
+        H = liq_h.shape[0]
+        liq_ok = jnp.broadcast_to(liq_h[:, None],
+                                  (H, r_cap.shape[0] // H)).reshape(-1)
+    else:
+        liq_ok = (state.hall_liq + D[LIQ])[r_hall] <= \
+            jt.hall_liq_cap[r_hall] + 1e-4
     return fits_row & hd_ok & liq_ok
 
 
@@ -210,11 +289,11 @@ def _kernel_feasible(jt: JaxTopology, state: HallState, dep: Deployment,
     n = jnp.asarray(n_in_row, jnp.float32)
     P = n * dep.rack_kw
     r_cap, r_load, r_feeds, r_nfeeds, _, _ = _row_view(jt, state, rows)
-    return _kernel_feasible_rows(
-        r_feeds, r_nfeeds, r_cap[:, POWER], state.lineup_ha,
-        state.lineup_tot, jt.lineup_cap, r_load[:, POWER], P, jt.ha_frac,
-        dep.tier == TIER_HA, jt.is_block, block_r=block_r,
-        interpret=interpret)
+    valid, cap, ha_l, tot_l = _feed_views(jt, state, r_feeds, rows)
+    return feasible_rows_from_views(
+        valid, cap, ha_l, tot_l, r_nfeeds, r_cap[:, POWER],
+        r_load[:, POWER], P, jt.ha_frac, dep.tier == TIER_HA, jt.is_block,
+        block_r=block_r, interpret=interpret)
 
 
 @jax.named_scope("repro.placement.row_feasible")
@@ -238,7 +317,7 @@ def row_feasible(jt: JaxTopology, state: HallState, dep: Deployment,
     n = jnp.asarray(n_in_row, jnp.float32)
     P = n * dep.rack_kw
     _, _, r_feeds, r_nfeeds, _, _ = _row_view(jt, state, rows)
-    valid, _, cap, ha_l, tot_l = _gather_feeds(jt, state, r_feeds)
+    valid, cap, ha_l, tot_l = _feed_views(jt, state, r_feeds, rows)
     nf = jnp.maximum(r_nfeeds, 1).astype(jnp.float32)        # [R|K]
     share = P / nf
     # distributed HA: simultaneous failover headroom on every parent (Eq. 1)
@@ -280,7 +359,7 @@ def row_scores(jt: JaxTopology, state: HallState, dep: Deployment,
     waste = (r_cap[:, POWER] - r_load[:, POWER] - P) / \
         jnp.maximum(r_cap[:, POWER], 1.0)
 
-    valid, _, cap, ha_l, tot_l = _gather_feeds(jt, state, r_feeds)
+    valid, cap, ha_l, tot_l = _feed_views(jt, state, r_feeds, rows)
     nf = jnp.maximum(r_nfeeds, 1).astype(jnp.float32)
     s = (P / nf)[:, None] / jnp.maximum(cap, 1.0)
     lhat = jnp.where(dep.tier == TIER_HA, ha_l, tot_l) / jnp.maximum(cap, 1.0)

@@ -320,7 +320,9 @@ def _prepare(axes: SweepAxes, n_halls_max: int,
     pre-split reference path (see `simulate_lifecycle`).
 
     Counts on its span: real trace `events`, the monthly scan's
-    `event_slots`, padded hall `rows` and the stacked inputs' `h2d_bytes`.
+    `event_slots`, padded hall `rows`, the stacked inputs' `h2d_bytes`
+    and `hall_local_configs`, the configurations whose topology takes
+    the hall-local placement path (`placement.JaxTopology`).
     """
     axes.validate()          # precise SweepValidationErrors, pre-compile
     B = len(axes)
@@ -346,8 +348,8 @@ def _prepare(axes: SweepAxes, n_halls_max: int,
         topos = [build_topology(d, H_max, rows_per_hall=R_pad,
                                 lineups_per_hall=X_pad)
                  for d in axes.designs]
-        jt = jax.tree.map(lambda *xs: jnp.stack(xs),
-                          *[pl.jax_topology(t) for t in topos])
+        jts = [pl.jax_topology(t) for t in topos]
+        jt = jax.tree.map(lambda *xs: jnp.stack(xs), *jts)
 
     with spans.span("repro.sweep.prepare.traces"):
         E_max = bucket(max(len(t) for t in traces), 64)
@@ -385,6 +387,8 @@ def _prepare(axes: SweepAxes, n_halls_max: int,
     spans.count("event_slots",
                 B * months * (e_max + (ep_max if split else 0)))
     spans.count("rows", B * H_max * R_pad)
+    spans.count("hall_local_configs",
+                sum(j.row_feeds_local is not None for j in jts))
     spans.count("h2d_bytes", sum(x.nbytes for x in jax.tree.leaves(args)))
     hd_scan = max(t.n_hd_rows for t in topos)
     return args, months, topos, X_pad, with_pods, _pod_scan_len(traces), \

@@ -157,6 +157,17 @@ def test_sweep_counts_are_the_padded_shapes(profiled):
     assert 0 < fin.counts["rows_built"] <= prep.counts["rows"]
 
 
+@pytest.mark.parametrize("prepare", ["repro.mc_sweep.prepare",
+                                     "repro.sweep.prepare"])
+def test_hall_local_configs_counts_every_tiled_config(profiled, prepare):
+    """Every configuration of a `build_topology` batch takes the
+    hall-local placement path, and the prepare span says so."""
+    recs, _, _ = profiled
+    _, _, by_name = _tree(recs, prepare.rsplit(".", 1)[0])
+    prep, = by_name[prepare]
+    assert prep.counts["hall_local_configs"] == len(DESIGNS)
+
+
 def test_profile_host_plane_holds_the_same_spans(profiled):
     recs, xplane, _ = profiled
     from jax.profiler import ProfileData
