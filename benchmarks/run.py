@@ -648,8 +648,8 @@ def mc_pod_speedup():
     t_split = t_legacy = 0.0
     dev, n_cfg = 0.0, 0
     for p in pods:
-        # first pair compiles both paths at the exact grid shape and
-        # window statics; the timed reps (min of 2, interleaved — 1-core
+        # first pair compiles both paths at the exact grid shape; the
+        # timed reps (min of 2, interleaved — 1-core
         # wall times are noisy) then measure execution + staging only
         rs = mc_sweep(axes, pod_racks=p, **kw)
         rl = mc_sweep(axes, pod_racks=p, legacy_pod_cond=True, **kw)

@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import arrivals, cost, placement as pl, projections as proj
 from . import throughput as tp
@@ -198,38 +198,37 @@ def _staged_topology(design: DesignSpec, rows_per_hall: int,
     return _TOPO_CACHE[key]
 
 
-def _mc_trial(jt_c, pol, t_a, t_b, k, *, harvest, with_pods, **statics):
+def _mc_trial(jt_c, pol, t_a, t_b, k, *, harvest, with_pods, **trial_kw):
     """One trial's device outputs.  The empty initial state is built
-    inside the trace (`init_state_from`), so every operand carries the
-    batch axes.  `statics` forwards the split-pods placement-mode
-    keywords (`split_pods`, `pod_windows`, `cluster_starts`,
+    inside the trace (`init_state_from`), so every operand but the
+    shared `windows` carries the batch axes.  `trial_kw` forwards the
+    split-pods placement-mode keywords (`split_pods`, `windows`,
     `pod_scan_len`, `hd_scan`) to `run_trial`."""
     state, res_a, res_b = run_trial(jt_c, pl.init_state_from(jt_c),
                                     t_a, t_b, pol, k, harvest, with_pods,
-                                    **statics)
+                                    **trial_kw)
     return (pl.lineup_stranding(jt_c, state),
             pl.hall_stranding(jt_c, state)[0],
             pl.deployed_kw(state),
             res_b.saturated, res_a.placed, res_b.placed)
 
 
-_MC_STATICS = ("harvest", "with_pods", "split_pods", "pod_windows",
-               "cluster_starts", "pod_scan_len", "hd_scan", "use_kernel",
-               "kernel_interpret")
+_MC_STATICS = ("harvest", "with_pods", "split_pods", "pod_scan_len",
+               "hd_scan", "use_kernel", "kernel_interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_MC_STATICS)
 def _mc_sweep_jit(jt, ta, tb, keys, policy, harvest, with_pods,
-                  split_pods=False, pod_windows=(0, 0),
-                  cluster_starts=(0, 0), pod_scan_len=pl.MAX_POD_RACKS,
-                  hd_scan=None, use_kernel=False, kernel_interpret=False):
+                  split_pods=False, windows=None,
+                  pod_scan_len=pl.MAX_POD_RACKS, hd_scan=None,
+                  use_kernel=False, kernel_interpret=False):
     """vmap `_mc_trial` over (configuration × trial): [B] topology /
-    policy axes outer, [B, T] trace/key axes inner."""
+    policy axes outer, [B, T] trace/key axes inner; the split-pods
+    `windows` are shared by every trial."""
     trial = functools.partial(
         _mc_trial, harvest=harvest, with_pods=with_pods,
-        split_pods=split_pods, pod_windows=pod_windows,
-        cluster_starts=cluster_starts, pod_scan_len=pod_scan_len,
-        hd_scan=hd_scan, use_kernel=use_kernel,
+        split_pods=split_pods, windows=windows,
+        pod_scan_len=pod_scan_len, hd_scan=hd_scan, use_kernel=use_kernel,
         kernel_interpret=kernel_interpret)
     per_cfg = jax.vmap(trial, in_axes=(None, None, 0, 0, 0))
     return jax.vmap(per_cfg)(jt, policy, ta, tb, keys)
@@ -238,9 +237,9 @@ def _mc_sweep_jit(jt, ta, tb, keys, policy, harvest, with_pods,
 @functools.partial(jax.jit, static_argnames=_MC_STATICS + ("mesh",),
                    donate_argnums=tuple(range(5)))
 def _mc_sharded_jit(jt, ta, tb, keys, policy, mesh, harvest, with_pods,
-                    split_pods=False, pod_windows=(0, 0),
-                    cluster_starts=(0, 0), pod_scan_len=pl.MAX_POD_RACKS,
-                    hd_scan=None, use_kernel=False, kernel_interpret=False):
+                    split_pods=False, windows=None,
+                    pod_scan_len=pl.MAX_POD_RACKS, hd_scan=None,
+                    use_kernel=False, kernel_interpret=False):
     """Sharded trial batch: operands arrive FLATTENED to one [B·T]
     (config × trial) axis — `sharded_mc_sweep` repeats the per-config
     topology/policy per trial — which a single `vmap` consumes under
@@ -250,27 +249,32 @@ def _mc_sharded_jit(jt, ta, tb, keys, policy, mesh, harvest, with_pods,
     (A nested config × trial vmap inside `shard_map` trips an XLA CPU
     compile crash; the flat axis sidesteps it and shards finer anyway.)
     Trials are independent, so out_specs stay sharded; no collectives.
-    Operand buffers are donated — the staged flat batch dies with the
-    dispatch."""
+    The split-pods `windows` are replicated.  Operand buffers are
+    donated — the staged flat batch dies with the dispatch."""
     spec = shax.batch_spec()
-    fn = jax.vmap(lambda jt_c, t_a, t_b, k, pol: _mc_trial(
-        jt_c, pol, t_a, t_b, k, harvest=harvest, with_pods=with_pods,
-        split_pods=split_pods, pod_windows=pod_windows,
-        cluster_starts=cluster_starts, pod_scan_len=pod_scan_len,
+    trial = functools.partial(
+        _mc_trial, harvest=harvest, with_pods=with_pods,
+        split_pods=split_pods, pod_scan_len=pod_scan_len,
         hd_scan=hd_scan, use_kernel=use_kernel,
-        kernel_interpret=kernel_interpret))
-    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 5,
+        kernel_interpret=kernel_interpret)
+
+    def shard_fn(jt_s, ta_s, tb_s, keys_s, pol_s, win):
+        return jax.vmap(lambda jt_c, t_a, t_b, k, pol: trial(
+            jt_c, pol, t_a, t_b, k, windows=win))(jt_s, ta_s, tb_s, keys_s,
+                                                   pol_s)
+
+    sharded = jax.shard_map(shard_fn, mesh=mesh,
+                            in_specs=(spec,) * 5 + (P(),),
                             out_specs=spec, check_vma=False)
-    return sharded(jt, ta, tb, keys, policy)
+    return sharded(jt, ta, tb, keys, policy, windows)
 
 
 @functools.partial(jax.jit, static_argnames=_MC_STATICS + ("mesh",),
                    donate_argnums=tuple(range(5)))
 def _mc_sharded2d_jit(jt, ta, tb, keys, policy, mesh, harvest, with_pods,
-                      split_pods=False, pod_windows=(0, 0),
-                      cluster_starts=(0, 0), pod_scan_len=pl.MAX_POD_RACKS,
-                      hd_scan=None, use_kernel=False,
-                      kernel_interpret=False):
+                      split_pods=False, windows=None,
+                      pod_scan_len=pl.MAX_POD_RACKS, hd_scan=None,
+                      use_kernel=False, kernel_interpret=False):
     """2-D grid sharding: the [B, T] trial grid block-shards over the
     (config × trial) mesh — configurations over `CONFIG_AXIS`, trial
     replicas over `TRIAL_AXIS` — while the [B] topology/policy leaves
@@ -279,19 +283,17 @@ def _mc_sharded2d_jit(jt, ta, tb, keys, policy, mesh, harvest, with_pods,
     on the host never happens: each shard repeats its own [b] slab
     across its [t] local trials *inside* the compiled program, flattens
     to one [b·t] axis for a single vmap (the nested-vmap XLA CPU crash
-    again), and reshapes back, so out_specs are grid-sharded [B, T]."""
+    again), and reshapes back, so out_specs are grid-sharded [B, T].
+    The split-pods `windows` are replicated."""
     cspec = shax.config_spec()
     gspec = shax.grid_spec()
     trial = functools.partial(
         _mc_trial, harvest=harvest, with_pods=with_pods,
-        split_pods=split_pods, pod_windows=pod_windows,
-        cluster_starts=cluster_starts, pod_scan_len=pod_scan_len,
+        split_pods=split_pods, pod_scan_len=pod_scan_len,
         hd_scan=hd_scan, use_kernel=use_kernel,
         kernel_interpret=kernel_interpret)
-    fn = jax.vmap(lambda jt_c, t_a, t_b, k, pol: trial(jt_c, pol, t_a,
-                                                       t_b, k))
 
-    def shard_fn(jt_s, ta_s, tb_s, keys_s, pol_s):
+    def shard_fn(jt_s, ta_s, tb_s, keys_s, pol_s, win):
         b, t = keys_s.shape[:2]
         # tile [b, …] → [b·t, …] with a GATHER, not broadcast/repeat:
         # broadcasting a config-sharded, trial-replicated operand inside
@@ -303,21 +305,24 @@ def _mc_sharded2d_jit(jt, ta, tb, keys, policy, mesh, harvest, with_pods,
         ta_f, tb_f, keys_f = jax.tree.map(
             lambda x: x.reshape((b * t,) + x.shape[2:]),
             (ta_s, tb_s, keys_s))
-        out = fn(jt_f, ta_f, tb_f, keys_f, pol_f)
+        out = jax.vmap(lambda jt_c, t_a, t_b, k, pol: trial(
+            jt_c, pol, t_a, t_b, k, windows=win))(jt_f, ta_f, tb_f, keys_f,
+                                                   pol_f)
         return jax.tree.map(
             lambda x: x.reshape((b, t) + x.shape[1:]), out)
 
     sharded = jax.shard_map(shard_fn, mesh=mesh,
-                            in_specs=(cspec, gspec, gspec, gspec, cspec),
+                            in_specs=(cspec, gspec, gspec, gspec, cspec,
+                                      P()),
                             out_specs=gspec, check_vma=False)
-    return sharded(jt, ta, tb, keys, policy)
+    return sharded(jt, ta, tb, keys, policy, windows)
 
 
 def _pod_geometry(batches) -> Tuple[int, int]:
     """(max, min) per-trial pod count over a list of `TraceBatch`es — the
-    static pod-window length and cluster-window start the split-pods
-    scan compiles.  Also validates the pods-first contract (mirroring
-    `fleet._event_windows`)."""
+    pod-window end and cluster-window start the split-pods scan runs
+    (passed as data, so they never reach a compile).  Also validates the
+    pods-first contract (mirroring `fleet._event_windows`)."""
     counts = np.concatenate([b.n_pods.ravel() for b in batches])
     for b in batches:
         ip = np.asarray(b.is_pod)
@@ -338,8 +343,12 @@ def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
     """Host-side staging shared by `mc_sweep` and `sharded_mc_sweep`:
     padded/stacked topologies ([B] leading axis), batched fill + refill
     trial traces ([B, T, E]), per-trial PRNG keys, per-config policies,
-    plus the static placement-mode keywords for the jitted trial
-    (`with_pods` / `split_pods` windows / `pod_scan_len` / `hd_scan`).
+    plus the placement-mode keywords for the jitted trial: the static
+    `with_pods` / `split_pods` / `pod_scan_len` / `hd_scan`, and with
+    split pods the traced `windows`, int32 ``(W_a, W_b, S_a, S_b)``: the
+    grid's largest pod count of the fill and the refill phase, then its
+    smallest, exactly (they change from seed to seed; as data they
+    leave every grid of one mix on one executable).
 
     Refill traces draw from the phase-1 stream of the *same* seed
     (`sample_mixed_traces(phase=1)`); the historical `seed + 1` refill
@@ -350,7 +359,10 @@ def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
     Counts on its span: trace `events` (fill and refill), padded `rows`,
     the stacked inputs' `h2d_bytes` and `hall_local_configs`, the
     configurations whose topology takes the hall-local placement path
-    (`placement.JaxTopology`)."""
+    (`placement.JaxTopology`); on pod grids also `pod_events` (real pod
+    arrivals) and `pod_racks` (their racks), and with split pods
+    `pod_steps`, the lane-steps of the pod window: configurations ×
+    trials × (W_a + W_b)."""
     axes.validate()          # precise SweepValidationErrors, pre-compile
     B = len(axes)
     R_pad = max(d.n_rows for d in axes.designs)
@@ -371,22 +383,22 @@ def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
     tbs = [gen(n_trials, E_b, seed=s, phase=1, sku_kw_override=kw)
            for s, kw in zip(axes.seeds, axes.sku_kw)]
     with_pods = any(bool(t.is_pod.any()) for t in tas + tbs)
-    statics = dict(with_pods=with_pods)
+    trial_kw = dict(with_pods=with_pods)
+    if with_pods:
+        spans.count("pod_events", sum(int(t.n_pods.sum())
+                                      for t in tas + tbs))
+        spans.count("pod_racks", sum(int(t.n_racks[t.is_pod].sum())
+                                     for t in tas + tbs))
     if with_pods and not legacy_pod_cond:
-        # windows bucket to 4 (pod window up, cluster start down) so
-        # same-shape grids over fresh seeds reuse the compiled executable
-        # despite per-seed pod-count jitter; the cost is at most 3 dead
-        # scan steps per window
         wa, sa = _pod_geometry(tas)
         wb, sb = _pod_geometry(tbs)
-        bucket = lambda n, E: min(-(-n // 4) * 4, E)
-        statics.update(
+        trial_kw.update(
             split_pods=True,
-            pod_windows=(bucket(wa, n_events), bucket(wb, E_b)),
-            cluster_starts=(sa // 4 * 4, sb // 4 * 4),
+            windows=np.array([wa, wb, sa, sb], np.int32),
             pod_scan_len=min(max(t.max_pod_racks for t in tas + tbs),
                              pl.MAX_POD_RACKS),
             hd_scan=max(s[0].n_hd_rows for s in staged))
+        spans.count("pod_steps", B * n_trials * (wa + wb))
     with spans.span("repro.mc_sweep.prepare.stage"):
         jt = jax.tree.map(lambda *xs: jnp.stack(xs),
                           *[s[1] for s in staged])
@@ -400,7 +412,7 @@ def _mc_prepare(axes: MCAxes, n_trials: int, n_events: int, year: int,
     spans.count("hall_local_configs",
                 sum(j.row_feeds_local is not None for _, j in staged))
     spans.count("h2d_bytes", sum(x.nbytes for x in jax.tree.leaves(args)))
-    return args, statics
+    return args, trial_kw
 
 
 @spans.spanned("repro.mc_sweep.finalize")
@@ -500,15 +512,16 @@ def mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
     """
     with spans.span("repro.mc_sweep", configs=len(axes),
                     trials=len(axes) * n_trials):
-        args, statics = _mc_prepare(axes, n_trials, n_events, year,
-                                    scenario, gpu_power_share, pod_racks,
-                                    quantum_racks, la_fraction,
-                                    single_sku_gpu, refill_events,
-                                    legacy_pod_cond)
+        args, trial_kw = _mc_prepare(axes, n_trials, n_events, year,
+                                     scenario, gpu_power_share, pod_racks,
+                                     quantum_racks, la_fraction,
+                                     single_sku_gpu, refill_events,
+                                     legacy_pod_cond)
         with spans.span("repro.mc_sweep.dispatch"):
             out = _mc_sweep_jit(*args, harvest=harvest,
                                 use_kernel=pl.resolve_use_kernel(use_kernel),
-                                kernel_interpret=kernel_interpret, **statics)
+                                kernel_interpret=kernel_interpret,
+                                **trial_kw)
         with spans.span("repro.mc_sweep.wait"):
             out = jax.block_until_ready(out)
         return _mc_finalize(
@@ -567,7 +580,7 @@ def sharded_mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
         return mc_sweep(axes, **kw)
 
     with spans.span("repro.mc_sweep", configs=B, trials=B * T):
-        (jt, ta, tb, keys, policy), statics = _mc_prepare(
+        (jt, ta, tb, keys, policy), trial_kw = _mc_prepare(
             axes, n_trials, n_events, year, scenario, gpu_power_share,
             pod_racks, quantum_racks, la_fraction, single_sku_gpu,
             refill_events, legacy_pod_cond)
@@ -601,7 +614,7 @@ def sharded_mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
                     cfg_leaves[0], ta, tb, keys, cfg_leaves[1],
                     harvest=harvest, mesh=mesh,
                     use_kernel=pl.resolve_use_kernel(use_kernel),
-                    kernel_interpret=kernel_interpret, **statics)
+                    kernel_interpret=kernel_interpret, **trial_kw)
             with spans.span("repro.mc_sweep.wait"):
                 out = jax.block_until_ready(out)
                 # drop padding on the host (see `sweep.sharded_sweep`)
@@ -631,7 +644,7 @@ def sharded_mc_sweep(axes: MCAxes, n_trials: int = 32, n_events: int = 600,
                 out = _mc_sharded_jit(
                     *args, harvest=harvest, mesh=mesh,
                     use_kernel=pl.resolve_use_kernel(use_kernel),
-                    kernel_interpret=kernel_interpret, **statics)
+                    kernel_interpret=kernel_interpret, **trial_kw)
             with spans.span("repro.mc_sweep.wait"):
                 out = jax.block_until_ready(out)
                 out = jax.tree.map(lambda x: np.asarray(x)[:B * T].reshape(

@@ -496,10 +496,15 @@ class _RangeEval:
     A width-1 vmap compiles a degenerate batch whose accumulation order
     differs bitwise from wider dispatches (observed on XLA:CPU), so
     single-config ranges duplicate their row to width 2 and keep row 0 —
-    bitwise identical to the same row inside any wider dispatch."""
+    bitwise identical to the same row inside any wider dispatch.
 
-    def __init__(self, args, jit_fn, statics: dict):
+    `operands` are traced keyword arguments every range shares unsliced
+    (the hall's split-pods `windows`)."""
+
+    def __init__(self, args, jit_fn, statics: dict,
+                 operands: dict | None = None):
         self.args, self.jit_fn, self.statics = args, jit_fn, statics
+        self.operands = operands or {}
         self._compiled: Dict[int, Callable] = {}
 
     def _slice(self, lo: int, hi: int):
@@ -515,17 +520,19 @@ class _RangeEval:
         width = max(hi - lo, 2)
         if width not in self._compiled:
             self._compiled[width] = self.jit_fn.lower(
-                *sl, **self.statics).compile()
+                *sl, **self.statics, **self.operands).compile()
         exe = self._compiled[width]
         if hi - lo == 1:
-            return lambda: jax.tree.map(lambda x: x[:1], exe(*sl))
-        return lambda: exe(*sl)
+            return lambda: jax.tree.map(lambda x: x[:1],
+                                        exe(*sl, **self.operands))
+        return lambda: exe(*sl, **self.operands)
 
     def shapes(self, lo: int, hi: int):
         """Output shapes of `[lo, hi)` from `jax.eval_shape` (no
         compile)."""
         out = jax.eval_shape(lambda: self.jit_fn(*self._slice(lo, hi),
-                                                 **self.statics))
+                                                 **self.statics,
+                                                 **self.operands))
         return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct((hi - lo,) + s.shape[1:],
                                            s.dtype), out)
@@ -623,16 +630,19 @@ def resilient_mc_sweep(axes: MCAxes, chunk_size: int | None = None,
     """`mc_sweep.mc_sweep` behind the resilient chunk executor (see
     `resilient_sweep`; chunks slice the configuration axis, trials ride
     inside their configuration)."""
-    args, statics = _mc_prepare(axes, n_trials, n_events, year, scenario,
-                                gpu_power_share, pod_racks, quantum_racks,
-                                la_fraction, single_sku_gpu, refill_events)
+    args, trial_kw = _mc_prepare(axes, n_trials, n_events, year, scenario,
+                                 gpu_power_share, pod_racks, quantum_racks,
+                                 la_fraction, single_sku_gpu, refill_events)
+    # the windows follow the traces, which the fingerprint hashes in `args`
+    windows = trial_kw.pop("windows", None)
     kw = dict(harvest=harvest,
               use_kernel=pl.resolve_use_kernel(use_kernel),
-              kernel_interpret=kernel_interpret, **statics)
+              kernel_interpret=kernel_interpret, **trial_kw)
     B = len(axes)
     chunk = chunk_size if chunk_size is not None else B
 
-    ex = _ChunkExecutor(_RangeEval(args, _mc_sweep_jit, kw), MC_FIELDS,
+    ex = _ChunkExecutor(_RangeEval(args, _mc_sweep_jit, kw,
+                                   dict(windows=windows)), MC_FIELDS,
                         detect=("deployed_kw",),
                         B=B, chunk_size=chunk,
                         checkpoint_dir=checkpoint_dir, plan=fault_plan,

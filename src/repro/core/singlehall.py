@@ -56,8 +56,7 @@ class TrialResult(NamedTuple):
 
 def _fill_phase(jt: JaxTopology, state: HallState, trace: TraceArrays,
                 policy, key, with_pods: bool = True,
-                split_pods: bool = False, pod_window: int = 0,
-                cluster_start: int = 0,
+                split_pods: bool = False, windows=None,
                 pod_scan_len: int = pl.MAX_POD_RACKS,
                 hd_scan: int | None = None, use_kernel: bool = False,
                 kernel_interpret: bool = False) -> TrialResult:
@@ -75,14 +74,15 @@ def _fill_phase(jt: JaxTopology, state: HallState, trace: TraceArrays,
       regression/benchmark reference (`legacy_pod_cond=True` upstream).
     * ``with_pods=True, split_pods=True`` — the split-trace fast path:
       the trace must be pods-first (`arrivals.sample_mixed_traces`
-      emits it that way), so a **pod window** over events
-      ``[0, pod_window)`` (live while ``i < n_pods``, `_place_pod` with
-      the static `pod_scan_len` rack scan and the HD-compacted `hd_scan`
-      row view) runs first, then a **cluster window** over
-      ``[cluster_start, E)`` (live while ``i >= n_pods``,
-      `place_cluster_in_row`).  `pod_window` must be ≥ every trial's pod
-      count and `cluster_start` ≤ every trial's pod count (upstream
-      computes the batch max/min).  Event order, the saturation streak
+      emits it that way), so a **pod window** over events ``[0, W)``
+      (live while ``i < n_pods``, `_place_pod` with the static
+      `pod_scan_len` rack scan and the HD-compacted `hd_scan` row view)
+      runs first, then a **cluster window** over ``[S, E)`` (live while
+      ``i >= n_pods``, `place_cluster_in_row`).  ``windows = (W, S)``
+      are traced int32 scalars, not compile-time constants, so every
+      grid of one trace mix runs one executable: `W` must be ≥ every
+      trial's pod count and `S` ≤ every trial's pod count (upstream
+      passes the grid's max/min).  Event order, the saturation streak
       and the per-event `fold_in(key, i)` keys are exactly the legacy
       path's, so results are bit-identical.
 
@@ -122,21 +122,29 @@ def _fill_phase(jt: JaxTopology, state: HallState, trace: TraceArrays,
                            streak >= SATURATION_FAILS)
 
     n_pods = jnp.sum(trace.is_pod.astype(jnp.int32))
+    pod_end, cluster_start = windows
 
     def window_step(place_fn, live_of):
-        def body(carry, i):
-            st, streak = carry
+        # outputs are written at the loop index with dynamic slices; the
+        # two windows are live-disjoint, so an event keeps what the
+        # window that owns it wrote (the -1/0 defaults otherwise)
+        def body(i, carry):
+            st, streak, placed, rows, counts = carry
             frozen = streak >= SATURATION_FAILS
             dep = trace.event(i)
             k = jax.random.fold_in(key, i)
-            st2, ok, rows, counts = place_fn(st, dep, k)
+            st2, ok, rows_i, counts_i = place_fn(st, dep, k)
             live = live_of(i)
             ok = ok & ~frozen & live
             st = pl._tree_where(ok, st2, st)
-            rows = jnp.where(ok, rows, -1)
-            counts = jnp.where(ok, counts, 0.0)
+            at = lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, False)
+            put = lambda x, v: jax.lax.dynamic_update_index_in_dim(x, v,
+                                                                   i, 0)
+            placed = put(placed, at(placed) | ok)
+            rows = put(rows, jnp.where(ok, rows_i, at(rows)))
+            counts = put(counts, jnp.where(ok, counts_i, at(counts)))
             streak = jnp.where(live, jnp.where(ok, 0, streak + 1), streak)
-            return (st, streak), (ok, rows, counts)
+            return st, streak, placed, rows, counts
         return body
 
     def pod_place(st, dep, k):
@@ -150,34 +158,16 @@ def _fill_phase(jt: JaxTopology, state: HallState, trace: TraceArrays,
                                        use_kernel=use_kernel,
                                        interpret=kernel_interpret)[:4]
 
-    carry = (state, jnp.zeros((), jnp.int32))
-    placed = jnp.zeros((E,), bool)
-    rows = jnp.full((E, pl.MAX_POD_RACKS), -1, jnp.int32)
-    counts = jnp.zeros((E, pl.MAX_POD_RACKS), jnp.float32)
-    if pod_window > 0:
-        carry, (ok_p, rows_p, counts_p) = jax.lax.scan(
-            window_step(pod_place, lambda i: i < n_pods), carry,
-            jnp.arange(pod_window))
-        placed = placed.at[:pod_window].set(ok_p)
-        rows = rows.at[:pod_window].set(rows_p)
-        counts = counts.at[:pod_window].set(counts_p)
-    if cluster_start < E:
-        carry, (ok_c, rows_c, counts_c) = jax.lax.scan(
-            window_step(cluster_place, lambda i: i >= n_pods), carry,
-            jnp.arange(cluster_start, E))
-        # the two windows are live-disjoint, so a cluster result only ever
-        # lands where the pod window left the -1/0 defaults
-        ok_full = jnp.zeros((E,), bool).at[cluster_start:].set(ok_c)
-        placed = placed | ok_full
-        rows = jnp.where(
-            ok_full[:, None],
-            jnp.full((E, pl.MAX_POD_RACKS), -1,
-                     jnp.int32).at[cluster_start:].set(rows_c), rows)
-        counts = jnp.where(
-            ok_full[:, None],
-            jnp.zeros((E, pl.MAX_POD_RACKS)).at[cluster_start:].set(counts_c),
-            counts)
-    state, streak = carry
+    carry = (state, jnp.zeros((), jnp.int32), jnp.zeros((E,), bool),
+             jnp.full((E, pl.MAX_POD_RACKS), -1, jnp.int32),
+             jnp.zeros((E, pl.MAX_POD_RACKS), jnp.float32))
+    carry = jax.lax.fori_loop(0, pod_end,
+                              window_step(pod_place, lambda i: i < n_pods),
+                              carry)
+    carry = jax.lax.fori_loop(cluster_start, E,
+                              window_step(cluster_place,
+                                          lambda i: i >= n_pods), carry)
+    state, streak, placed, rows, counts = carry
     return TrialResult(state, placed, rows, counts,
                        streak >= SATURATION_FAILS)
 
@@ -193,29 +183,31 @@ def _apply_harvest(jt: JaxTopology, res: TrialResult,
 def run_trial(jt: JaxTopology, topo_init: HallState,
               trace_a: TraceArrays, trace_b: TraceArrays,
               policy, key, harvest: bool = True, with_pods: bool = True,
-              split_pods: bool = False,
-              pod_windows: tuple = (0, 0), cluster_starts: tuple = (0, 0),
+              split_pods: bool = False, windows=None,
               pod_scan_len: int = pl.MAX_POD_RACKS,
               hd_scan: int | None = None, use_kernel: bool = False,
               kernel_interpret: bool = False):
     """One MC trial: fill → harvest → refill.  Returns final state and the
-    two phase results.  Every keyword is static (jit static argnames
-    upstream): the non-harvest variant never traces the harvest branch,
-    pod-free traces compile the cheap single-row placement, and
-    `split_pods=True` compiles the split-trace pod fast path —
-    `pod_windows` / `cluster_starts` are the (fill, refill) window bounds
-    and `pod_scan_len` / `hd_scan` the pod rack-scan trims (see
-    `_fill_phase`).  `use_kernel` / `kernel_interpret` route placement's
-    line-up power feasibility through the Pallas kernel (bitwise-identical
-    results; see `placement.place_in_row`)."""
+    two phase results.  Every keyword but `windows` is static (jit
+    static argnames upstream): the non-harvest variant never traces the
+    harvest branch, pod-free traces compile the cheap single-row
+    placement, and `split_pods=True` compiles the split-trace pod fast
+    path, with `pod_scan_len` / `hd_scan` the pod rack-scan trims.  Its
+    window bounds are data: `windows` is int32 ``(W_a, W_b, S_a, S_b)``,
+    the pod-window ends and cluster-window starts of the fill and
+    refill phases (see `_fill_phase`).  `use_kernel` / `kernel_interpret`
+    route placement's line-up power feasibility through the Pallas kernel
+    (bitwise-identical results; see `placement.place_in_row`)."""
     ka, kb = jax.random.split(key)
+    phase = lambda p: None if windows is None else (windows[p],
+                                                     windows[p + 2])
     res_a = _fill_phase(jt, topo_init, trace_a, policy, ka, with_pods,
-                        split_pods, pod_windows[0], cluster_starts[0],
-                        pod_scan_len, hd_scan, use_kernel, kernel_interpret)
+                        split_pods, phase(0), pod_scan_len, hd_scan,
+                        use_kernel, kernel_interpret)
     state = _apply_harvest(jt, res_a, trace_a) if harvest else res_a.state
     res_b = _fill_phase(jt, state, trace_b, policy, kb, with_pods,
-                        split_pods, pod_windows[1], cluster_starts[1],
-                        pod_scan_len, hd_scan, use_kernel, kernel_interpret)
+                        split_pods, phase(1), pod_scan_len, hd_scan,
+                        use_kernel, kernel_interpret)
     return res_b.state, res_a, res_b
 
 

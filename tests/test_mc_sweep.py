@@ -193,6 +193,66 @@ def test_mc_split_pods_matches_legacy_cond(pod_racks):
                                       getattr(res_legacy, f), err_msg=f)
 
 
+# Two one-configuration pod grids of equal shapes whose exact windows
+# (W_a, W_b, S_a, S_b) are (9, 4, 7, 2) and (14, 6, 4, 0): rounded to
+# multiples of 4 they would still differ, so window sizes baked into the
+# program as constants would compile each grid anew.
+POD_GRID_SEEDS = (1, 4)
+POD_GRID_KW = dict(n_trials=4, n_events=80, refill_events=30, year=2030,
+                   scenario=proj.HIGH, pod_racks=3)
+
+_ONE_COMPILE_CHILD = """
+import functools, json, sys
+import jax
+from repro.core import hierarchy as h, mc_sweep as mcs
+path, kw, seeds = sys.argv[1], json.loads(sys.argv[2]), json.loads(sys.argv[3])
+if path == "mc_sweep":
+    run, jitted = mcs.mc_sweep, mcs._mc_sweep_jit
+else:
+    run = functools.partial(mcs.sharded_mc_sweep, devices=jax.devices()[:2],
+                            mesh_shape=(1, 2))
+    jitted = mcs._mc_sharded2d_jit
+out = {"compiled": [], "windows": [], "placed": []}
+for s in seeds:
+    axes = mcs.MCAxes.zip(designs=[h.get_design("4N/3")], seeds=[s])
+    prep_kw = {k: kw[k] for k in ("year", "scenario", "pod_racks")}
+    _, trial_kw = mcs._mc_prepare(
+        axes, kw["n_trials"], kw["n_events"], gpu_power_share=0.6,
+        quantum_racks=10, la_fraction=0.0, single_sku_gpu=False,
+        refill_events=kw["refill_events"], **prep_kw)
+    res = run(axes, **kw)
+    out["compiled"].append(jitted._cache_size())
+    out["windows"].append(trial_kw["windows"].tolist())
+    out["placed"].append(int(res.placed_a.sum() + res.placed_b.sum()))
+print(json.dumps(out))
+"""
+
+
+@pytest.mark.parametrize("path", ["mc_sweep", "sharded_2d"])
+def test_pod_grids_share_one_executable(path):
+    """The split-pods windows are data, not constants of the program: two
+    pod grids of one mix and equal shapes whose windows differ run one
+    compiled hall program, unsharded and on a (1, 2) (config × trial)
+    mesh of two host devices (a child process, so the device count holds
+    whatever jax this process already started)."""
+    import json
+    import subprocess
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src"),
+                    os.environ.get("PYTHONPATH", "")]))
+    proc = subprocess.run(
+        [sys.executable, "-c", _ONE_COMPILE_CHILD, path,
+         json.dumps(POD_GRID_KW), json.dumps(POD_GRID_SEEDS)],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["windows"] == [[9, 4, 7, 2], [14, 6, 4, 0]]
+    assert out["compiled"] == [1, 1]
+    assert all(p > 0 for p in out["placed"])
+
+
 def test_refill_stream_decorrelated_from_adjacent_seed():
     """Refill traces draw from the phase-1 stream of the same seed; the
     old `seed + 1` refill was bitwise the next configuration's fill

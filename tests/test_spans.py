@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 
 from repro.core import hierarchy as h, placement as pl, projections as proj
-from repro.core.arrivals import EnvelopeSpec, generate_fleet_trace
+from repro.core.arrivals import (EnvelopeSpec, generate_fleet_trace,
+                                 sample_mixed_traces)
 from repro.core.fleet import _auto_halls
 from repro.core.mc_sweep import MCAxes, _mc_prepare, _mc_sweep_jit, mc_sweep
 from repro.core.sweep import SweepAxes, _prepare, _sweep_jit, sweep
@@ -155,6 +156,37 @@ def test_sweep_counts_are_the_padded_shapes(profiled):
     assert fin.counts["rows_built"] == sum(
         int(n) * d.n_rows for n, d in zip(res.n_halls_built, designs))
     assert 0 < fin.counts["rows_built"] <= prep.counts["rows"]
+
+
+def test_mc_pod_counts_are_their_definitions(tmp_path):
+    """On a split-pods grid the prepare span counts `pod_events`, the real
+    pod arrivals, `pod_racks`, their racks, and `pod_steps`, the pod
+    window's lane-steps: configurations × trials × (W_a + W_b), with the
+    windows the grid's largest pod counts of the fill and the refill."""
+    pod = 7
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    spans.clear()
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        _, trial_kw = _mc_prepare(_mc_axes(), T, E, 2028, proj.HIGH, 0.6,
+                                  pod, 10, 0.0, False, E_B)
+    prep, = [r for r in spans.records()
+             if r.name == "repro.mc_sweep.prepare"]
+    spans.clear()
+    gen = lambda n, phase: sample_mixed_traces(
+        T, n, year=2028, scenario=proj.HIGH, gpu_power_share=0.6,
+        pod_racks=pod, quantum_racks=10, la_fraction=0.0, seed=5,
+        phase=phase)
+    fill, refill = gen(E, 0), gen(E_B, 1)
+    B = len(DESIGNS)
+    pods = int(fill.n_pods.sum() + refill.n_pods.sum())
+    assert pods > 0
+    assert prep.counts["pod_events"] == B * pods
+    assert prep.counts["pod_racks"] == B * pod * pods
+    W_a, W_b = int(fill.n_pods.max()), int(refill.n_pods.max())
+    assert prep.counts["pod_steps"] == B * T * (W_a + W_b)
+    assert trial_kw["windows"].tolist() == [
+        W_a, W_b, int(fill.n_pods.min()), int(refill.n_pods.min())]
 
 
 @pytest.mark.parametrize("prepare", ["repro.mc_sweep.prepare",
